@@ -26,7 +26,6 @@ path or the training hand-off trips the gate.
 from __future__ import annotations
 
 import math
-import time
 
 import numpy as np
 
@@ -38,7 +37,7 @@ from repro.data.synthetic import generate_world
 from repro.kernels import ops
 from repro.tess import Tesseract, tesseract_stats
 
-from .queries import TRIP_DAY, build_catalog, region_for
+from .queries import TRIP_DAY, build_catalog, region_for, time_best
 
 __all__ = ["run"]
 
@@ -58,25 +57,6 @@ def analytics_tesseracts():
     }
 
 
-def _sync(out):
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:
-        pass
-    return out
-
-
-def _time(fn, repeats=2):
-    _sync(fn())                              # warm (jit compile etc.)
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = _sync(fn())
-        best = min(best, time.perf_counter() - t0)
-    return out, best * 1e3                   # ms
-
-
 def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
     rows: list = []
     # same floor as the tesseract suite: below ~0.2 the synthetic week is
@@ -94,7 +74,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
         flow = fdb("Trips").tesseract(tess).map(lambda p: proto(id=p.id))
         results, times = {}, {}
         for bname, eng in engines.items():
-            res, ms = _time(lambda e=eng: e.collect(flow))
+            res, ms = time_best(lambda e=eng: e.collect(flow), repeats=2)
             results[bname], times[bname] = res, ms
         ids = {b: np.sort(r.batch["id"].values)
                for b, r in results.items()}
@@ -153,7 +133,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
         model, losses = ds.fit(steps=60, lr=2e-3, batch=256)
         return ds, losses
 
-    (ds, losses), ms = _time(ttm)
+    (ds, losses), ms = time_best(ttm, repeats=2)
     trained = bool(len(ds) > 0 and losses[-1] < losses[0])
     all_parity &= trained
     rows.append({

@@ -23,7 +23,6 @@ any suite reports a false one (the CI bench smoke gate).
 from __future__ import annotations
 
 import os
-import time
 
 import numpy as np
 
@@ -32,7 +31,8 @@ from repro.kernels import fused as fused_kernels
 from repro.fdb.index import bitmap_from_ids, bitmap_full
 from repro.kernels import ops as kernel_ops
 
-from .queries import QUERIES, build_catalog, q_variability
+from .queries import (QUERIES, build_catalog, q_variability, sync,
+                      time_best)
 
 __all__ = ["run", "batches_identical"]
 
@@ -56,27 +56,6 @@ def batches_identical(a, b) -> bool:
     return True
 
 
-def _sync(out):
-    """jax dispatch is async: block on any device values reachable from
-    ``out`` so the clock stops at completion, not at enqueue."""
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:
-        pass
-    return out
-
-
-def _time(fn, repeats=3):
-    _sync(fn())                              # warm (jit compile etc.)
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = _sync(fn())
-        best = min(best, time.perf_counter() - t0)
-    return out, best * 1e3                   # ms
-
-
 def _bench_primitives(rows, print_fn):
     """Backend primitive microbenches: the three hot-path ops, both ways."""
     rng = np.random.default_rng(0)
@@ -95,7 +74,7 @@ def _bench_primitives(rows, print_fn):
                 ("compact_1M", lambda: be.compact_mask(mask)),
                 ("segment_agg_1M_1024g",
                  lambda: be.segment_aggregate(codes, vals, 1024))]:
-            _, ms = _time(fn)
+            _, ms = time_best(fn)
             rows.append({"name": f"backend_{bname}_{op_name}",
                          "us_per_call": round(ms * 1e3, 1),
                          "derived": f"{n / (ms * 1e3):.1f} Melem/s"})
@@ -125,7 +104,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
         for bname, eng in engines.items():
             if bname == "jax":
                 kernel_ops.reset_launch_counts()
-            res, ms = _time(lambda e=eng: e.collect(flow), repeats=2)
+            res, ms = time_best(lambda e=eng: e.collect(flow), repeats=2)
             results[bname], times[bname] = res, ms
             if bname != "jax":
                 continue
@@ -140,7 +119,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
                 # per-stage device ms (upload/probe/refine/compact/agg)
                 # for ONE post-warm collect, so compile time stays out
                 fused_kernels.reset_stage_times()
-                _sync(eng.collect(flow))
+                sync(eng.collect(flow))
                 stages = {k: round(v, 3)
                           for k, v in fused_kernels.stage_times().items()}
         parity = batches_identical(results["numpy"].batch,

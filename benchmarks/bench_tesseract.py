@@ -30,7 +30,6 @@ regions the index must prune ≥ 90 % of trips before the exact pass.
 from __future__ import annotations
 
 import math
-import time
 
 import numpy as np
 
@@ -42,7 +41,7 @@ from repro.kernels import ops
 from repro.tess import tesseract_stats
 
 from .queries import (ORDERED_TRIP_QUERIES, TRIP_QUERIES, q_tesseract,
-                      tesseract_for)
+                      tesseract_for, time_best)
 
 __all__ = ["run"]
 
@@ -56,27 +55,6 @@ def _first_hit_parity(db, tess) -> bool:
     _, tab_j = get_backend("jax").refine_tracks_batched(
         batches, tess.field, cons, with_first_hits=True)
     return all(np.array_equal(a, b) for a, b in zip(tab_n, tab_j))
-
-
-def _sync(out):
-    """jax dispatch is async: block on any device values reachable from
-    ``out`` so the clock stops at completion, not at enqueue."""
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:
-        pass
-    return out
-
-
-def _time(fn, repeats=3):
-    _sync(fn())                              # warm (jit compile etc.)
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = _sync(fn())
-        best = min(best, time.perf_counter() - t0)
-    return out, best * 1e3                   # ms
 
 
 def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
@@ -102,7 +80,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
         tess = tesseract_for(legs, ordered=ordered)
         results, times = {}, {}
         for bname, eng in engines.items():
-            res, ms = _time(lambda e=eng: e.collect(flow), repeats=2)
+            res, ms = time_best(lambda e=eng: e.collect(flow), repeats=2)
             results[bname], times[bname] = res, ms
         ids = {b: np.sort(r.batch["id"].values)
                for b, r in results.items()}

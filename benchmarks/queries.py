@@ -16,6 +16,10 @@ families:
 """
 from __future__ import annotations
 
+import time
+
+import jax
+
 from repro.core import P, proto, IN, BETWEEN, group, fdb
 from repro.data.synthetic import (CITIES, BAY_AREA, city_region,
                                   generate_world)
@@ -24,9 +28,28 @@ from repro.fdb import build_fdb
 from repro.geo import AreaTree
 from repro.tess import Tesseract
 
-__all__ = ["build_catalog", "region_for", "q_variability", "QUERIES",
-           "tesseract_for", "q_tesseract", "TRIP_QUERIES", "TRIP_DAY",
-           "ORDERED_TRIP_QUERIES"]
+__all__ = ["sync", "time_best", "build_catalog", "region_for",
+           "q_variability", "QUERIES", "tesseract_for", "q_tesseract",
+           "TRIP_QUERIES", "TRIP_DAY", "ORDERED_TRIP_QUERIES"]
+
+
+def sync(out):
+    """Block on the device values reachable from ``out``: jax dispatch is
+    async, so a clock must stop at completion, not at enqueue."""
+    jax.block_until_ready(out)
+    return out
+
+
+def time_best(fn, repeats: int = 3):
+    """``(result, best wall ms of repeats)`` after one warm-up call (jit
+    compile and priming stay out of the timed calls)."""
+    sync(fn())
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        out = sync(fn())
+        best = min(best, time.perf_counter() - t0)
+    return out, best * 1e3
 
 
 def build_catalog(scale: float = 1.0, num_shards: int = 20,

@@ -11,9 +11,9 @@ parity), so the perf trajectory can be tracked across PRs
 baseline).
 
 Exit status is the CI contract: **non-zero whenever any suite reports a
-false parity bit** (numpy oracle ≠ jax batched path), and — under
-``--json`` — whenever a suite errored outright, so the bench smoke job
-cannot go green on broken output.
+false parity bit** (numpy oracle ≠ jax batched path) or errored outright,
+so the bench smoke job cannot go green on broken output.  The persistent
+compile cache is on (``repro.compile_cache``).
 """
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ import argparse
 import json
 import os
 import sys
+import traceback
 
 
 def _write_json(suite: str, rows: list, scale: float, out_dir: str) -> str:
@@ -48,6 +49,8 @@ def _write_json(suite: str, rows: list, scale: float, out_dir: str) -> str:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
     from .suites import SUITES
 
     ap = argparse.ArgumentParser()
@@ -68,6 +71,7 @@ def main() -> None:
                          "run eagerly, so wall times are not the fused "
                          "single-dispatch numbers)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.profile:
         os.environ["REPRO_EXEC_PROFILE"] = "1"
 
@@ -100,6 +104,7 @@ def main() -> None:
         try:
             suite_rows = fn() or []
         except Exception as e:  # keep the harness going; report at end
+            traceback.print_exc()
             print(f"  BENCH FAILED: {name}: {e!r}", file=sys.stderr)
             suite_rows = [{"name": f"{name}_FAILED", "error": repr(e)}]
         all_rows.extend(suite_rows)
@@ -121,7 +126,7 @@ def main() -> None:
     if parity_bad:
         print(f"\nPARITY FAILURE: {parity_bad}", file=sys.stderr)
         sys.exit(1)
-    if errors and args.json:
+    if errors:
         print(f"\nSUITE ERRORS: {errors}", file=sys.stderr)
         sys.exit(1)
 

@@ -91,6 +91,7 @@ import numpy as np
 
 from ..fdb.index import (bitmap_from_ids, bitmap_stack, ids_from_bitmap,
                          mask_from_bitmap)
+from .device_cache import device_form, host_form
 from .refine import (FIRST_HIT_NONE, LAST_HIT_NONE, pack_constraints,
                      pack_constraints_multi, pack_track_points,
                      reduction_verdict, refine_tracks_host)
@@ -545,7 +546,8 @@ class JaxBackend(ExecBackend):
         self.device_cache = DeviceCache(jax)
         #: when set to a list, the fused path appends ("prefetch", n) /
         #: ("wave_done", shard_ids) markers — the prefetch-ordering tests'
-        #: evidence that wave k+1 staged before wave k finished
+        #: evidence that wave k+1 staged before wave k finished — and
+        #: ("wave_devices", names): where each fused wave's outputs live
         self.trace_events: Optional[list] = None
         # weak: a collected FDb drops out, so a new FDb reusing the same
         # address still primes, and a finalizer evicts its buffers.
@@ -599,7 +601,7 @@ class JaxBackend(ExecBackend):
         if impl == "reference":
             # float64 + row-order accumulation: bit-equal to the numpy
             # oracle, and the same segment math the kernel implements.
-            with self._jax.experimental.enable_x64():
+            with self._jax.enable_x64(True):
                 cnt, s, s2 = self._ops.segment_agg(
                     self._jnp.asarray(codes32),
                     self._jnp.asarray(np.asarray(values, dtype=np.float64)),
@@ -846,9 +848,11 @@ class JaxBackend(ExecBackend):
         return pts, rows
 
     def _dev(self, arr: np.ndarray):
-        """Device buffer for ``arr`` (resident when primed, else upload)."""
+        """Device buffer for ``arr`` (resident when primed, else upload),
+        in the cache's :func:`~repro.exec.device_cache.device_form`."""
         dev = self.device_cache.get(arr)
-        return dev if dev is not None else self._jnp.asarray(arr)
+        return dev if dev is not None else self._jnp.asarray(
+            device_form(arr))
 
     def _order_ok(self, fh_hi, fh_lo, i: int, j: int):
         """Device-side strict first-hit compare for ordering edge (i, j):
@@ -1240,44 +1244,43 @@ class JaxBackend(ExecBackend):
             if dev is None:
                 cols[p] = c.gather(ids)
                 continue
-            with self._jax.experimental.enable_x64():
-                if c.row_splits is None:
-                    if dev_ids is None:
-                        dev_ids = self._jnp.asarray(ids)
-                    vals = np.asarray(dev[dev_ids])
-                    cols[p] = Column(vals, None, c.vocab)
-                    continue
-                # device-side ragged gather: only the per-doc spans (one
-                # entry per selected doc) go host→device; the O(points)
-                # spans-concatenate index build and value gather run on
-                # device against the resident CSR value buffer
-                starts = c.row_splits[ids]
-                ends = c.row_splits[ids + 1]
-                new_splits = np.zeros(ids.size + 1, dtype=np.int64)
-                np.cumsum(ends - starts, out=new_splits[1:])
-                total = int(new_splits[-1])
-                if total == 0:
-                    vals = c.values[:0].copy()
-                else:
-                    jnp = self._jnp
-                    splits_d = jnp.asarray(new_splits)
-                    pos = jnp.arange(total, dtype=jnp.int64)
-                    row = jnp.searchsorted(splits_d, pos,
-                                           side="right") - 1
-                    flat = jnp.asarray(starts)[row] + pos - splits_d[row]
-                    vals = np.asarray(dev[flat])
-                cols[p] = Column(vals, new_splits, c.vocab)
+            if c.row_splits is None:
+                if dev_ids is None:
+                    dev_ids = self._jnp.asarray(ids.astype(np.int32))
+                vals = host_form(dev[dev_ids], c.values.dtype)
+                cols[p] = Column(vals, None, c.vocab)
+                continue
+            # device-side ragged gather: only the per-doc spans (one
+            # entry per selected doc) go host→device; the O(points)
+            # spans-concatenate index build and value gather run on
+            # device against the resident CSR value buffer (int32
+            # positions: a shard holds fewer than 2**31 points)
+            starts = c.row_splits[ids]
+            ends = c.row_splits[ids + 1]
+            new_splits = np.zeros(ids.size + 1, dtype=np.int64)
+            np.cumsum(ends - starts, out=new_splits[1:])
+            total = int(new_splits[-1])
+            if total == 0:
+                vals = c.values[:0].copy()
+            else:
+                jnp = self._jnp
+                splits_d = jnp.asarray(new_splits.astype(np.int32))
+                pos = jnp.arange(total, dtype=jnp.int32)
+                row = jnp.searchsorted(splits_d, pos, side="right") - 1
+                flat = jnp.asarray(starts.astype(np.int32))[row] + pos \
+                    - splits_d[row]
+                vals = host_form(dev[flat], c.values.dtype)
+            cols[p] = Column(vals, new_splits, c.vocab)
         return ColumnBatch(sub.schema, cols, ids.size)
 
     # ----------------------------------------------------- fused wave path
     def postings_bitmap(self, ids, t_min, t_max, t0, t1, n_docs):
         """Postings OR + span prune as one device pass over the resident
         ``t_min``/``t_max`` buffers (see ``kernels.fused``)."""
-        with self._jax.experimental.enable_x64():
-            tmin_d, tmax_d = self._dev(t_min), self._dev(t_max)
         bm = self._ops.postings_bitmap(np.asarray(ids, dtype=np.int64),
-                                       tmin_d, tmax_d, float(t0), float(t1),
-                                       n_docs, impl=self._impl())
+                                       self._dev(t_min), self._dev(t_max),
+                                       float(t0), float(t1), n_docs,
+                                       impl=self._impl())
         return np.asarray(bm, dtype=np.uint32)
 
     def segment_hll(self, codes, reg_idx, ranks, num_groups: int,
@@ -1357,7 +1360,7 @@ class JaxBackend(ExecBackend):
             if vp is None:
                 # count-only plan: a zeros stack so the segment stage
                 # still returns per-group row counts
-                with self._jax.experimental.enable_x64():
+                with self._jax.enable_x64(True):
                     vals_dev.append(jnp.zeros((len(shards), n_max), dt))
                 continue
             vsrc = tuple(id(sh.batch[vp].values) for sh in shards)
@@ -1370,7 +1373,7 @@ class JaxBackend(ExecBackend):
                     if sh.n:
                         stack[i, :sh.n] = np.asarray(sh.batch[vp].values,
                                                      dt)
-                with self._jax.experimental.enable_x64():
+                with self._jax.enable_x64(True):
                     dv = jnp.asarray(stack)
                 if vok:
                     self.device_cache.put_keyed(vkey, dv)
@@ -1463,6 +1466,9 @@ class JaxBackend(ExecBackend):
             vals_dev, num_docs=n_max, edges=edges, min_counts=mcs,
             dwells=dws, total_groups=total, impl=impl, profile=profile,
             minmax=minmax)
+        if self.trace_events is not None:
+            self.trace_events.append(
+                ("wave_devices", tuple(str(d) for d in sel_idx.devices())))
         # stage wave k+1's buffers before wave k's outputs sync to host
         if prefetch_shards:
             self.prefetch_wave(prefetch_shards, refine, agg)
@@ -1655,7 +1661,7 @@ class JaxBackend(ExecBackend):
         states = [(np.asarray(k), list(slots)) for k, slots in states]
         live = [st for st in states if len(st[0]) and st[1]]
         mesh = make_exec_mesh(len(parts) if parts else 0)
-        with self._jax.experimental.enable_x64():
+        with self._jax.enable_x64(True):
             if not live:
                 # nothing selected anywhere — still one combine launch,
                 # keeping the launch contract exact (cf. all-empty waves)

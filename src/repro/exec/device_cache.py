@@ -21,10 +21,12 @@ fresh delta buffers cost a host→device copy (``put`` on a known id is a
 dict hit).  ``stats()["buffers"]`` therefore grows by exactly the delta
 between generations, which the streaming tests assert.
 
-Device puts run under ``jax.experimental.enable_x64`` so int64/float64/
-uint64 buffers keep their width — the parity contract is byte-identical
-results against the numpy oracle, and a silent f64→f32 truncation at put
-time would break it.
+64-bit buffers (int64/float64/uint64) go to the device as their uint32
+bit words, shape ``[..., 2]`` in (lo, hi) order (:func:`device_form`).  The
+parity contract is byte-identical results against the numpy oracle: a
+TPU emulates float64 with two float32s, which would round the values, and
+a put without x64 would truncate them to 32 bits.  Word buffers move
+every bit unchanged, and readers view them back on the host.
 
 On top of the identity-keyed buffers the cache holds **keyed derived
 entries** (:meth:`put_keyed` / :meth:`get_keyed`): wave-stacked buffers the
@@ -43,7 +45,26 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["DeviceCache"]
+__all__ = ["DeviceCache", "device_form", "host_form"]
+
+
+def device_form(arr: np.ndarray) -> np.ndarray:
+    """The array a buffer is kept as on device: 64-bit dtypes as their
+    uint32 words ``[..., 2]`` (a view, no copy), everything else as is."""
+    if arr.dtype.itemsize != 8:
+        return arr
+    arr = np.ascontiguousarray(arr)
+    return arr.view(np.uint32).reshape(arr.shape + (2,))
+
+
+def host_form(words, dtype) -> np.ndarray:
+    """Inverse of :func:`device_form` for a buffer read back from device."""
+    words = np.asarray(words)
+    dtype = np.dtype(dtype)
+    if dtype.itemsize != 8:
+        return words.astype(dtype, copy=False)
+    words = np.ascontiguousarray(words, dtype=np.uint32)
+    return words.view(dtype).reshape(words.shape[:-1])
 
 
 class DeviceCache:
@@ -92,8 +113,7 @@ class DeviceCache:
             hit = self._buffers.get(key)
         if hit is not None:
             return hit[1]
-        with self._jax.experimental.enable_x64():
-            dev = self._jnp.asarray(arr)
+        dev = self._jnp.asarray(device_form(arr))
         with self._lock:
             self._buffers[key] = (arr, dev)
         return dev

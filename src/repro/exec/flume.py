@@ -179,10 +179,12 @@ class FlumeEngine:
             # concurrently on the stage's worker budget, same per-task
             # checkpoint files as the per-shard path.  A wave that errors
             # must not abort its siblings: completed waves still commit
-            # their checkpoints (the point of stage-level recovery), and
-            # the failed wave's shards fall through to the per-shard
-            # machinery below, which retries or raises loudly.
+            # their checkpoints (the point of stage-level recovery).  A
+            # simulated machine failure sends the wave's shards to the
+            # per-shard machinery below, which retries or raises loudly;
+            # any other error is raised once the siblings have committed.
             remaining: List[int] = []
+            wave_error: Optional[Exception] = None
             todo_set = set(todo)
             parts = (pplan.parts if pplan is not None else [list(todo)])
             # waves form *within* each partition (checkpointed shards
@@ -202,8 +204,11 @@ class FlumeEngine:
                 for fut, wave in futs:
                     try:
                         done, failed = fut.result()
-                    except Exception:
+                    except TaskFailure:
                         remaining.extend(wave)
+                        continue
+                    except Exception as e:
+                        wave_error = wave_error or e
                         continue
                     for out in done:
                         results[out.shard_id] = out
@@ -211,6 +216,8 @@ class FlumeEngine:
                             out, self._ckpt_path(stage_dir, out.shard_id))
                     self.stats["tasks_run"] += len(done)
                     remaining.extend(failed)
+            if wave_error is not None:
+                raise wave_error
             todo = remaining
 
         if not todo:

@@ -64,68 +64,41 @@ def bitset_binary(a: jnp.ndarray, b: jnp.ndarray, op: str = "and",
     return out.reshape(-1)[:w]
 
 
-def _intersect_kernel(stack_ref, o_ref, cnt_ref):
-    """AND-reduce K bitmaps for one word-block + popcount the result."""
-    k = stack_ref.shape[0]
-    acc = stack_ref[0]
-    for i in range(1, k):           # K is small & static (probes per query)
-        acc = acc & stack_ref[i]
-    o_ref[...] = acc
-    x = acc
+def _popcount(x):
+    """Per-word set-bit count of a uint32 array (SWAR), as int32."""
     x = x - ((x >> 1) & jnp.uint32(0x55555555))
     x = (x & jnp.uint32(0x33333333)) + ((x >> 2) & jnp.uint32(0x33333333))
     x = (x + (x >> 4)) & jnp.uint32(0x0F0F0F0F)
-    per_word = (x * jnp.uint32(0x01010101)) >> jnp.uint32(24)
-    cnt_ref[0, 0] = per_word.astype(jnp.int32).sum()
+    return ((x * jnp.uint32(0x01010101)) >> jnp.uint32(24)).astype(jnp.int32)
+
+
+def _intersect_batched_kernel(stack_ref, o_ref, cnt_ref):
+    """One (shard, word-block) grid step: AND-reduce that shard's K probes
+    for the block and popcount the result.  The count leaves as 128
+    per-lane partial sums: a (1, 128) row is a lane-aligned block, where a
+    single scalar per block would be a store the TPU cannot make to VMEM."""
+    k = stack_ref.shape[1]
+    acc = stack_ref[0, 0, 0]                   # (8, L) uint32
+    for i in range(1, k):           # K is small & static (probes per query)
+        acc = acc & stack_ref[0, i, 0]
+    o_ref[0, 0] = acc
+    per_lane = jnp.sum(_popcount(acc), axis=0, keepdims=True,
+                       dtype=jnp.int32)                     # (1, L)
+    part = per_lane[:, 0:128]
+    for j in range(128, per_lane.shape[1], 128):
+        part = part + per_lane[:, j:j + 128]
+    cnt_ref[0, 0] = part
 
 
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
 def bitmap_intersect(stack: jnp.ndarray,
                      block_words: int = DEFAULT_BLOCK_WORDS,
                      interpret: bool = False):
-    """AND-reduce probe bitmaps [K, W] → (bitmap [W], total popcount).
-
-    The grid walks word-blocks; each step reduces all K probes for its
-    block (K is tiny — one per index probe) and emits a per-block count;
-    the host-side sum of the per-block counts is ``rows_selected``.
-    """
-    k, w = stack.shape
-    padded = pl.cdiv(w, block_words) * block_words
-    s_p = jnp.zeros((k, padded), jnp.uint32).at[:, :w].set(stack)
-    s2 = s_p.reshape(k, -1, 8, block_words // 8)
-    nblk = s2.shape[1]
-    out, cnt = pl.pallas_call(
-        _intersect_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((k, 1, 8, block_words // 8),
-                               lambda i: (0, i, 0, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 8, block_words // 8), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblk, 8, block_words // 8), jnp.uint32),
-            jax.ShapeDtypeStruct((nblk, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(s2)
-    return out.reshape(-1)[:w], cnt.sum()
-
-
-def _intersect_batched_kernel(stack_ref, o_ref, cnt_ref):
-    """One (shard, word-block) grid step: AND-reduce that shard's K probes
-    for the block + popcount."""
-    k = stack_ref.shape[1]
-    acc = stack_ref[0, 0, 0]
-    for i in range(1, k):           # K is small & static (probes per query)
-        acc = acc & stack_ref[0, i, 0]
-    o_ref[...] = acc[None, None]
-    x = acc
-    x = x - ((x >> 1) & jnp.uint32(0x55555555))
-    x = (x & jnp.uint32(0x33333333)) + ((x >> 2) & jnp.uint32(0x33333333))
-    x = (x + (x >> 4)) & jnp.uint32(0x0F0F0F0F)
-    per_word = (x * jnp.uint32(0x01010101)) >> jnp.uint32(24)
-    cnt_ref[0, 0] = per_word.astype(jnp.int32).sum()
+    """AND-reduce probe bitmaps [K, W] → (bitmap [W], total popcount):
+    the one-shard case of :func:`bitmap_intersect_batched`."""
+    bm, cnt = bitmap_intersect_batched(stack[None], block_words=block_words,
+                                       interpret=interpret)
+    return bm[0], cnt[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_words", "interpret"))
@@ -141,24 +114,24 @@ def bitmap_intersect_batched(stack: jnp.ndarray,
     words are zero, so AND keeps the pad region clear.
     """
     s, k, w = stack.shape
+    lanes = block_words // 8
     padded = pl.cdiv(w, block_words) * block_words
     s_p = jnp.zeros((s, k, padded), jnp.uint32).at[:, :, :w].set(stack)
-    s2 = s_p.reshape(s, k, -1, 8, block_words // 8)
+    s2 = s_p.reshape(s, k, -1, 8, lanes)
     nblk = s2.shape[2]
     out, cnt = pl.pallas_call(
         _intersect_batched_kernel,
         grid=(s, nblk),
-        in_specs=[pl.BlockSpec((1, k, 1, 8, block_words // 8),
+        in_specs=[pl.BlockSpec((1, k, 1, 8, lanes),
                                lambda i, j: (i, 0, j, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, 1, 8, block_words // 8),
-                         lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, 8, lanes), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 128), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((s, nblk, 8, block_words // 8), jnp.uint32),
-            jax.ShapeDtypeStruct((s, nblk), jnp.int32),
+            jax.ShapeDtypeStruct((s, nblk, 8, lanes), jnp.uint32),
+            jax.ShapeDtypeStruct((s, nblk, 1, 128), jnp.int32),
         ],
         interpret=interpret,
     )(s2)
-    return out.reshape(s, -1)[:, :w], cnt.sum(axis=1)
+    return out.reshape(s, -1)[:, :w], cnt.sum(axis=(1, 2, 3))

@@ -8,7 +8,7 @@ XLA (it is memory-bound either way).
 The kernel walks row-blocks sequentially, carrying the running count in
 SMEM scratch — the canonical "scan with carry" pattern on TPU where grid
 steps execute in order.  Within a block, a 2-D (8, L) tile is scanned
-row-major: lane-wise cumsum + per-sublane offsets.
+row-major: a lane-wise scan plus per-sublane offsets.
 """
 from __future__ import annotations
 
@@ -19,93 +19,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
-__all__ = ["mask_prefix_sum", "compact", "mask_prefix_sum_batched",
-           "compact_batched"]
+__all__ = ["compact", "mask_prefix_sum_batched", "compact_batched"]
 
 DEFAULT_BLOCK = 8 * 512
 
 
-def _scan_kernel(mask_ref, pos_ref, total_ref, carry_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry_ref[0, 0] = 0
-
-    x = mask_ref[...].astype(jnp.int32)            # (1, 8, L)
-    lane_cs = jnp.cumsum(x, axis=2)                # inclusive along lanes
-    row_tot = lane_cs[:, :, -1]                    # (1, 8)
-    row_off = jnp.cumsum(row_tot, axis=1) - row_tot
-    carry = carry_ref[0, 0]
-    pos_ref[...] = lane_cs - x + row_off[:, :, None] + carry   # exclusive
-    block_total = row_tot.sum()
-    carry_ref[0, 0] = carry + block_total
-    total_ref[0, 0] = carry + block_total          # running total per block
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def mask_prefix_sum(mask: jnp.ndarray, block: int = DEFAULT_BLOCK,
-                    interpret: bool = False):
-    """mask [N] bool → (exclusive prefix sum [N] int32, count int32)."""
-    n = mask.shape[0]
-    if n == 0:    # zero-size grid: nothing to scan (empty candidate sets)
-        return jnp.zeros((0,), jnp.int32), jnp.int32(0)
-    padded = pl.cdiv(n, block) * block
-    m_p = jnp.zeros((padded,), jnp.bool_).at[:n].set(mask)
-    m2 = m_p.reshape(-1, 8, block // 8)
-    nblk = m2.shape[0]
-    pos, totals = pl.pallas_call(
-        _scan_kernel,
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((1, 8, block // 8), lambda i: (i, 0, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 8, block // 8), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(m2.shape, jnp.int32),
-            jax.ShapeDtypeStruct((nblk, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(m2)
-    return pos.reshape(-1)[:n], totals[-1, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def compact(mask: jnp.ndarray, block: int = DEFAULT_BLOCK,
-            interpret: bool = False):
-    """mask [N] → (indices [N] int32, -1 padded; count int32)."""
-    n = mask.shape[0]
-    pos, count = mask_prefix_sum(mask, block=block, interpret=interpret)
-    slot = jnp.where(mask, pos, n)
-    idx = jnp.full((n,), -1, jnp.int32)
-    idx = idx.at[slot].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
-    return idx, count
+def _inclusive_scan(x, axis: int):
+    """Inclusive prefix sum of an int32 tile along ``axis`` in log2 steps
+    of shift-and-add (Hillis–Steele).  Mosaic has no cumsum lowering; a
+    roll is one cross-lane (or cross-sublane) rotate per step."""
+    n = x.shape[axis]
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < n:
+        x = x + jnp.where(pos >= shift, pltpu.roll(x, shift, axis), 0)
+        shift *= 2
+    return x
 
 
 def _scan_batched_kernel(mask_ref, pos_ref, total_ref, carry_ref):
     """Per-(shard, row-block) scan step; the carry resets at each shard's
-    first block, so one launch scans a whole wave of shards."""
+    first block, so one launch scans a whole wave of shards.  A (8, L)
+    tile is scanned row-major: lane-wise scan plus per-sublane offsets."""
+    i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        carry_ref[0, 0] = 0
+        carry_ref[0] = 0
 
-    x = mask_ref[...].astype(jnp.int32)            # (1, 1, 8, L)
-    lane_cs = jnp.cumsum(x, axis=3)                # inclusive along lanes
-    row_tot = lane_cs[..., -1]                     # (1, 1, 8)
-    row_off = jnp.cumsum(row_tot, axis=2) - row_tot
-    carry = carry_ref[0, 0]
-    pos_ref[...] = lane_cs - x + row_off[..., None] + carry    # exclusive
-    block_total = row_tot.sum()
-    carry_ref[0, 0] = carry + block_total
-    total_ref[0, 0] = carry + block_total          # running total per block
+    x = mask_ref[0, 0].astype(jnp.int32)           # (8, L)
+    lane_cs = _inclusive_scan(x, 1)                # inclusive along lanes
+    last = x.shape[1] - 1
+    row_tot = jnp.broadcast_to(lane_cs[:, last:], x.shape)
+    row_off = _inclusive_scan(row_tot, 0) - row_tot
+    carry = carry_ref[0]
+    pos_ref[0, 0] = lane_cs - x + row_off + carry   # exclusive
+    carry_ref[0] = carry + jnp.sum(x, dtype=jnp.int32)
+    total_ref[i] = carry_ref[0]                    # last block's write wins
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -118,6 +69,8 @@ def mask_prefix_sum_batched(masks: jnp.ndarray, block: int = DEFAULT_BLOCK,
     running count carried in SMEM and reset per shard, so the whole wave is
     one kernel launch.  Grid order is sequential in both dimensions
     (``arbitrary`` semantics) — the scan-with-carry pattern requires it.
+    The per-shard counts are one SMEM word each, so S stays far below
+    SMEM's size for any wave the engine builds.
     """
     s, n = masks.shape
     if n == 0 or s == 0:
@@ -133,18 +86,18 @@ def mask_prefix_sum_batched(masks: jnp.ndarray, block: int = DEFAULT_BLOCK,
                                lambda i, j: (i, j, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, 1, 8, block // 8), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(m2.shape, jnp.int32),
-            jax.ShapeDtypeStruct((s, nblk), jnp.int32),
+            jax.ShapeDtypeStruct((s,), jnp.int32),
         ],
-        scratch_shapes=[pltpu.SMEM((1, 1), jnp.int32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(m2)
-    return pos.reshape(s, -1)[:, :n], totals[:, -1]
+    return pos.reshape(s, -1)[:, :n], totals
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -160,3 +113,13 @@ def compact_batched(masks: jnp.ndarray, block: int = DEFAULT_BLOCK,
     idx = jnp.full((s, n), -1, jnp.int32)
     idx = idx.at[rows, slot].set(cols, mode="drop")
     return idx, counts
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def compact(mask: jnp.ndarray, block: int = DEFAULT_BLOCK,
+            interpret: bool = False):
+    """mask [N] → (indices [N] int32, -1 padded; count int32): the
+    one-shard case of :func:`compact_batched`."""
+    idx, counts = compact_batched(mask[None], block=block,
+                                  interpret=interpret)
+    return idx[0], counts[0]

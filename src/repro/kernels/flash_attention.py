@@ -24,8 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["flash_attention"]
 
 DEFAULT_BLOCK_Q = 256
@@ -153,7 +151,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 128), jnp.float32),     # l
             pltpu.VMEM((bq, d), jnp.float32),       # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q3, k3, v3)

@@ -49,6 +49,7 @@ import numpy as np
 
 from . import bitset as _bitset
 from . import compact as _compact
+from . import f64_words as _f64
 from . import ref as _ref
 from . import refine as _refine
 from . import segment_agg as _seg
@@ -104,16 +105,6 @@ def _mask_stage(bm, ns, num_docs: int):
     return (bits != 0) & (docs[None, :] < ns[:, None])
 
 
-def _unpack_sort_key(hi, lo):
-    """uint32 (hi, lo) packed-timestamp words → float64 (inverse of the
-    order-preserving IEEE-754 sort-key map).  Needs x64 enabled — callers
-    wrap dwell-carrying pipelines in ``enable_x64``."""
-    k = (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
-    sign = (k >> jnp.uint64(63)) != 0
-    bits = jnp.where(sign, k & ~(jnp.uint64(1) << jnp.uint64(63)), ~k)
-    return jax.lax.bitcast_convert_type(bits, jnp.float64)
-
-
 def _reduction_verdict(fh_hi, fh_lo, lh_hi, lh_lo, cnt, edges,
                        min_counts, dwells):
     """Per-doc verdict recomputed from the reduction tables (leading axes
@@ -134,9 +125,10 @@ def _reduction_verdict(fh_hi, fh_lo, lh_hi, lh_lo, cnt, edges,
             ok = cnt[..., c, :] >= k
         d = dwells[c] if c < len(dwells) else None
         if d is not None:
-            span = _unpack_sort_key(lh_hi[..., c, :], lh_lo[..., c, :]) \
-                - _unpack_sort_key(fh_hi[..., c, :], fh_lo[..., c, :])
-            ok = ok & doc_hit & (span >= float(d))
+            span_ok = _f64.span_at_least(fh_hi[..., c, :], fh_lo[..., c, :],
+                                         lh_hi[..., c, :], lh_lo[..., c, :],
+                                         d)
+            ok = ok & doc_hit & span_ok
         out = ok if out is None else (out & ok)
     for i, j in edges:               # A-then-B: first hit of i before j's
         a_hi, a_lo = fh_hi[..., i, :], fh_lo[..., i, :]
@@ -239,10 +231,7 @@ def _fused_fn(impl: str, num_docs: int,
                               minmax)
         return cand, sel_idx, sel_counts, segs
 
-    # Donating the probe stack lets XLA reuse its buffer for the stage
-    # intermediates on TPU; CPU donation only emits warnings.
-    donate = (0,) if jax.default_backend() == "tpu" else ()
-    return jax.jit(fn, donate_argnums=donate)
+    return jax.jit(fn)
 
 
 def _profiled(impl, probe_stack, ns, pts, rows, cov, codes, vals,
@@ -283,20 +272,18 @@ def run_wave_fused(probe_stack, ns, pts=None, rows=None, cov=None,
     ``minmax`` flags which value slots also reduce per-group min/max
     (5-tuple partials); ``min_counts``/``dwells`` apply per-constraint
     count/dwell verdicts inside the refine stage — same dispatch, no
-    extra launches.  Dwell verdicts unpack packed timestamps to float64
-    in the jit epilogue, so dwell-carrying pipelines run under
-    ``enable_x64`` on every impl (the integer kernels are unaffected)."""
+    extra launches.  Dwell verdicts subtract the packed timestamps with
+    exact uint32 word arithmetic (``kernels.f64_words``)."""
     edges = tuple(tuple(e) for e in edges)
     min_counts = tuple(int(k) for k in min_counts)
     dwells = tuple(None if d is None else float(d) for d in dwells)
     vals = tuple(vals)
     minmax = tuple(bool(m) for m in minmax)
     has_refine = pts is not None
-    any_dwell = any(d is not None for d in dwells)
     # reference: f64 value stacks + f64 accumulation, bit-equal to the
     # host oracle
-    ctx = jax.experimental.enable_x64() \
-        if (impl == "reference" or any_dwell) else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if impl == "reference" \
+        else contextlib.nullcontext()
     with ctx:
         if profile:
             return _profiled(impl, probe_stack, ns, pts, rows, cov,
@@ -414,11 +401,10 @@ def run_wave_fused_multi(probe_stacks, ns, pts=None, rows=None, cov=None,
     dwells_multi = tuple(tuple(None if d is None else float(d) for d in dw)
                          for dw in dwells_multi)
     has_refine = pts is not None
-    any_dwell = any(d is not None for dw in dwells_multi for d in dw)
     fn = _fused_multi_fn(impl, num_docs, edges_multi, has_refine,
                          min_counts_multi, dwells_multi)
-    ctx = jax.experimental.enable_x64() \
-        if (impl == "reference" or any_dwell) else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if impl == "reference" \
+        else contextlib.nullcontext()
     with ctx:
         return fn(probe_stacks, ns, pts, rows, cov)
 
@@ -431,26 +417,41 @@ def run_wave_fused_multi(probe_stacks, ns, pts=None, rows=None, cov=None,
 def _postings_bitmap(ids, t_min, t_max, t0, t1, n_docs: int):
     nw = (n_docs + 31) // 32
     hit = jnp.zeros((nw * 32,), jnp.bool_).at[ids].set(True, mode="drop")
-    overlap = jnp.zeros((nw * 32,), jnp.bool_).at[:n_docs].set(
-        (t_min <= t1) & (t_max >= t0))
+    lo_key = _f64.key_from_bits(t_min[:, 1], t_min[:, 0])
+    hi_key = _f64.key_from_bits(t_max[:, 1], t_max[:, 0])
+    span_ok = (_f64.less_equal(*lo_key, t1[0], t1[1])
+               & _f64.less_equal(t0[0], t0[1], *hi_key)
+               & ~_f64.is_nan(t_min[:, 1], t_min[:, 0])
+               & ~_f64.is_nan(t_max[:, 1], t_max[:, 0]))
+    overlap = jnp.zeros((nw * 32,), jnp.bool_).at[:n_docs].set(span_ok)
     bits = (hit & overlap).reshape(nw, 32).astype(jnp.uint32)
     # doc 32·w + b → word w, bit b: the bitmap_from_ids word layout
     return (bits << jnp.arange(32, dtype=jnp.uint32)[None, :]).sum(
         axis=1, dtype=jnp.uint32)
 
 
+def _key_words(t: float):
+    """Host float64 → its sort-key (hi, lo) words as a uint32 [2] array."""
+    k = int(np.float64(float(t) + 0.0).view(np.uint64))
+    k = (~k & 0xFFFFFFFFFFFFFFFF) if k >> 63 else k | (1 << 63)
+    return np.array([k >> 32, k & 0xFFFFFFFF], np.uint32)
+
+
 def postings_bitmap(ids, t_min, t_max, t0, t1, n_docs: int):
     """OR doc ``ids`` into a word bitmap and prune docs whose ``[t_min,
     t_max]`` track span misses ``[t0, t1]`` — the host tail of
     ``SpaceTimeIndex.lookup`` as one device pass (pure-jnp lowering under
-    every ``impl``; scatter-OR has no Pallas kernel).  Runs under
-    ``enable_x64`` so the float64 span compare matches the host exactly.
+    every ``impl``; scatter-OR has no Pallas kernel).  ``t_min``/``t_max``
+    are float64 spans as uint32 ``[n, 2]`` (lo, hi) bit words (the
+    ``DeviceCache`` device form); the span compare runs on their sort
+    keys, so it matches the host's float64 compare exactly.
     """
     if n_docs <= 0:
         return jnp.zeros((0,), jnp.uint32)
-    with jax.experimental.enable_x64():
-        return _postings_bitmap(jnp.asarray(ids), t_min, t_max,
-                                jnp.float64(t0), jnp.float64(t1), n_docs)
+    if np.isnan(t0) or np.isnan(t1):
+        return jnp.zeros(((n_docs + 31) // 32,), jnp.uint32)
+    return _postings_bitmap(jnp.asarray(np.asarray(ids, np.int32)), t_min,
+                            t_max, _key_words(t0), _key_words(t1), n_docs)
 
 
 # --------------------------------------------------------------------------

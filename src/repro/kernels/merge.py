@@ -15,7 +15,8 @@ to the union key space by the host, in a single device dispatch:
 
 Under a multi-device ``"part"`` mesh the leading states axis is sharded
 with ``shard_map`` and the per-device partial accumulations combine via
-``psum`` / ``pmin`` / ``pmax``.  On a one-device host the mesh axis has
+``psum`` (min / max gather the per-device planes and reduce them
+locally).  On a one-device host the mesh axis has
 size 1, so the shard_map path is still exercised while the arithmetic
 stays the exact sequential order — CPU CI emulates P>1 partitions
 without changing a single result bit.  Precision note: like the Mixer's
@@ -29,7 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["merge_partials"]
@@ -58,9 +59,11 @@ def _sharded_combine(mesh):
     spec = P("part")
 
     @jax.jit
+    # check_vma=False: the gathered min/max planes are replicated, which
+    # shard_map cannot infer through all_gather
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(spec,) * 6,
-                       out_specs=(P(),) * 6)
+                       out_specs=(P(),) * 6, check_vma=False)
     def run(cnt, s, s2, mn, mx, msk):
         c, a, a2, lo, hi, m = _combine_local(cnt, s, s2, mn, mx, msk)
         # in-order within a device, then a cross-device combine.  With a
@@ -68,9 +71,12 @@ def _sharded_combine(mesh):
         # sequential oracle order; counts (ints) and min/max/OR are exact
         # at any axis size, float sums become per-device subtotals on a
         # real multi-device mesh (the usual tree-reduce trade)
+        # min/max gather the per-device planes and reduce locally: a TPU
+        # all-reduce lowers only sums for 64-bit floats
         return (jax.lax.psum(c, "part"), jax.lax.psum(a, "part"),
                 jax.lax.psum(a2, "part"),
-                jax.lax.pmin(lo, "part"), jax.lax.pmax(hi, "part"),
+                jax.lax.all_gather(lo, "part").min(axis=0),
+                jax.lax.all_gather(hi, "part").max(axis=0),
                 jax.lax.psum(m.astype(jnp.int32), "part") > 0)
 
     return run

@@ -21,14 +21,22 @@ Input packing (all integer words, so the pass is exact on any impl):
     (win_lo, win_hi) window, all as (hi, lo) word pairs.  Padding slots use
     an empty range (lo = 2^64−1, hi = 0) and never hit.
 
-The kernel walks a ``(doc-block, point-block)`` grid like ``segment_agg``:
-per point block it evaluates all C constraints against the R ranges on the
+The kernel walks a ``(query, shard, doc-block, point-block)`` grid: per
+point block it evaluates all C constraints against the R ranges on the
 VPU, reduces hits per doc through the one-hot ``rows == doc_iota`` compare,
 and OR-accumulates a **per-doc constraint bitset** (bit c set ⇔ some point
 satisfied constraint c).  A doc passes iff its bitset is full — computed in
-the jit epilogue.  ``refine_tracks_batched`` stacks a whole wave of shards
-(ragged P and doc counts zero-padded) and adds a leading shard grid axis,
-so a wave costs **one** launch, mirroring ``compact_batched``.
+the jit epilogue.  A wave of shards (ragged P and doc counts zero-padded)
+rides the shard axis and Q coalesced queries the query axis, so a wave
+costs **one** launch, mirroring ``compact_batched``; the single-query and
+single-shard entry points are the Q=1 / S=1 cases of the same call.
+
+On the device the point words and doc ids travel as one int32 ``[8, T]``
+tile per point block (rows: key hi/lo, time hi/lo, doc id, 3 zero rows),
+transposed in the kernel so points run down the sublanes and docs and
+ranges along the lanes.  Words are mapped to int32 by flipping the sign
+bit, which keeps their unsigned order: the TPU reduces int32 but not
+uint32.  The epilogue flips the hit tables back.
 
 Under ``with_first_hits`` the same grid walk also min-reduces a
 per-(doc × constraint) **first-hit** timestamp — the lexicographic
@@ -55,8 +63,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["refine_tracks", "refine_tracks_batched", "refine_tracks_multi",
            "DEFAULT_POINT_BLOCK", "DEFAULT_DOC_BLOCK"]
@@ -67,7 +74,7 @@ _RANGE_PAD = 128               # cover-range slots padded to the lane width
 
 
 def _ge(a_hi, a_lo, b_hi, b_lo):
-    """a >= b over (hi, lo) uint32 word pairs (64-bit lexicographic)."""
+    """a >= b over (hi, lo) word pairs (64-bit lexicographic)."""
     return (a_hi > b_hi) | ((a_hi == b_hi) & (a_lo >= b_lo))
 
 
@@ -80,82 +87,94 @@ def _le(a_hi, a_lo, b_hi, b_lo):
 
 
 _FH_SENT = 0xFFFFFFFF          # first-hit "no hit" sentinel word
+_SIGN = 0x80000000
+_I32_MAX = 0x7FFFFFFF          # _FH_SENT in signed order
+_I32_MIN = -0x80000000         # last-hit sentinel word 0 in signed order
 
 
-def _refine_kernel(pts_ref, rows_ref, cov_ref, out_ref, *aux_refs,
+def _signed(x):
+    """uint32 words → int32 with the same order (sign bit flipped)."""
+    return jax.lax.bitcast_convert_type(x ^ jnp.uint32(_SIGN), jnp.int32)
+
+
+def _unsigned(x):
+    """Inverse of :func:`_signed`."""
+    return jax.lax.bitcast_convert_type(x, jnp.uint32) ^ jnp.uint32(_SIGN)
+
+
+def _refine_kernel(words_ref, cov_ref, out_ref, *aux_refs,
                    doc_block: int, n_constraints: int):
-    g = pl.program_id(1)
-    t = pl.program_id(2)
-    sent = jnp.uint32(_FH_SENT)
+    """One (query, shard, doc-block, point-block) grid step.  ``aux_refs``
+    are the first-hit (hi, lo) planes, then last-hit (hi, lo) and the hit
+    count under analytics; all words are in signed order."""
+    g = pl.program_id(2)
+    t = pl.program_id(3)
+    top = jnp.int32(_I32_MAX)
+    bottom = jnp.int32(_I32_MIN)
 
     @pl.when(t == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
         for fh in aux_refs[:2]:                    # first-hit planes → sent
-            fh[...] = jnp.full_like(fh, sent)
-        for ref in aux_refs[2:]:                   # last-hit / count → 0
-            ref[...] = jnp.zeros_like(ref)
+            fh[...] = jnp.full_like(fh, top)
+        for lh in aux_refs[2:4]:                   # last-hit planes → sent
+            lh[...] = jnp.full_like(lh, bottom)
+        for cnt in aux_refs[4:]:
+            cnt[...] = jnp.zeros_like(cnt)
 
-    k_hi = pts_ref[0, 0, :][:, None]               # (T, 1) uint32
-    k_lo = pts_ref[0, 1, :][:, None]
-    t_hi = pts_ref[0, 2, :][:, None]
-    t_lo = pts_ref[0, 3, :][:, None]
-    rows = rows_ref[0, :]                          # (T,) int32
+    w = words_ref[0].T                             # (T, 8) int32
+    k_hi, k_lo, t_hi, t_lo, rows = (w[:, i:i + 1] for i in range(5))
     docs = g * doc_block + jax.lax.broadcasted_iota(
         jnp.int32, (1, doc_block), 1)              # (1, D)
-    onehot = rows[:, None] == docs                 # (T, D) bool
+    onehot = rows == docs                          # (T, D) bool
     acc = jnp.zeros((1, doc_block), jnp.int32)
     for c in range(n_constraints):
-        lo_hi = cov_ref[c, 0, :][None, :]          # (1, R)
-        lo_lo = cov_ref[c, 1, :][None, :]
-        hi_hi = cov_ref[c, 2, :][None, :]
-        hi_lo = cov_ref[c, 3, :][None, :]
-        w0_hi = cov_ref[c, 4, :][None, :]
-        w0_lo = cov_ref[c, 5, :][None, :]
-        w1_hi = cov_ref[c, 6, :][None, :]
-        w1_lo = cov_ref[c, 7, :][None, :]
+        cv = cov_ref[0, c]                         # (8, R)
+        lo_hi, lo_lo, hi_hi, hi_lo, w0_hi, w0_lo, w1_hi, w1_lo = (
+            cv[i:i + 1, :] for i in range(8))      # (1, R) each
         hit = (_ge(k_hi, k_lo, lo_hi, lo_lo)       # key in [lo, hi)
                & _lt(k_hi, k_lo, hi_hi, hi_lo)
                & _ge(t_hi, t_lo, w0_hi, w0_lo)     # t in [w0, w1]
                & _le(t_hi, t_lo, w1_hi, w1_lo))
-        hit_pt = jnp.any(hit, axis=1)              # (T,)
-        hit2d = onehot & hit_pt[:, None]           # (T, D)
-        contrib = jnp.any(hit2d, axis=0)           # (D,)
-        acc = acc | jnp.left_shift(contrib[None, :].astype(jnp.int32), c)
+        hit_pt = jnp.any(hit, axis=1, keepdims=True)          # (T, 1)
+        hit2d = onehot & hit_pt                               # (T, D)
+        contrib = jnp.any(hit2d, axis=0, keepdims=True)       # (1, D)
+        acc = acc | jnp.left_shift(contrib.astype(jnp.int32), c)
         if aux_refs:
             # per-doc lexicographic (t_hi, t_lo) min over this point
             # block, two passes: min hi, then min lo among points whose
             # hi equals that min (exact — the second pass only sees the
             # argmin-hi candidates; no-hit docs stay at the sentinel)
             fh_hi_ref, fh_lo_ref = aux_refs[0], aux_refs[1]
-            blk_hi = jnp.min(jnp.where(hit2d, t_hi, sent), axis=0)  # (D,)
-            at_min = hit2d & (t_hi == blk_hi[None, :])
-            blk_lo = jnp.min(jnp.where(at_min, t_lo, sent), axis=0)
-            acc_hi = fh_hi_ref[0, c, :]
-            acc_lo = fh_lo_ref[0, c, :]
-            take = (blk_hi < acc_hi) \
-                | ((blk_hi == acc_hi) & (blk_lo < acc_lo))
-            fh_hi_ref[0, c, :] = jnp.where(take, blk_hi, acc_hi)
-            fh_lo_ref[0, c, :] = jnp.where(take, blk_lo, acc_lo)
+            blk_hi = jnp.min(jnp.where(hit2d, t_hi, top), axis=0,
+                             keepdims=True)
+            at_min = hit2d & (t_hi == blk_hi)
+            blk_lo = jnp.min(jnp.where(at_min, t_lo, top), axis=0,
+                             keepdims=True)
+            acc_hi = fh_hi_ref[0, 0, c:c + 1, :]
+            acc_lo = fh_lo_ref[0, 0, c:c + 1, :]
+            take = _lt(blk_hi, blk_lo, acc_hi, acc_lo)
+            fh_hi_ref[0, 0, c:c + 1, :] = jnp.where(take, blk_hi, acc_hi)
+            fh_lo_ref[0, 0, c:c + 1, :] = jnp.where(take, blk_lo, acc_lo)
         if len(aux_refs) > 2:
-            # last-hit dual: lexicographic max with (0, 0) init — safe as
-            # a sentinel because packed key 0 only encodes −NaN, which
-            # never passes a window compare; count sums hits across the
-            # sequential point-grid axis
+            # last-hit dual: lexicographic max with a (0, 0) unsigned init
+            # — safe as a sentinel because packed key 0 only encodes −NaN,
+            # which never passes a window compare; count sums hits across
+            # the sequential point-grid axis
             lh_hi_ref, lh_lo_ref, cnt_ref = aux_refs[2:]
-            zero = jnp.uint32(0)
-            lblk_hi = jnp.max(jnp.where(hit2d, t_hi, zero), axis=0)
-            at_max = hit2d & (t_hi == lblk_hi[None, :])
-            lblk_lo = jnp.max(jnp.where(at_max, t_lo, zero), axis=0)
-            lacc_hi = lh_hi_ref[0, c, :]
-            lacc_lo = lh_lo_ref[0, c, :]
-            ltake = (lblk_hi > lacc_hi) \
-                | ((lblk_hi == lacc_hi) & (lblk_lo > lacc_lo))
-            lh_hi_ref[0, c, :] = jnp.where(ltake, lblk_hi, lacc_hi)
-            lh_lo_ref[0, c, :] = jnp.where(ltake, lblk_lo, lacc_lo)
-            cnt_ref[0, c, :] = cnt_ref[0, c, :] \
-                + jnp.sum(hit2d.astype(jnp.int32), axis=0)
-    out_ref[...] = out_ref[...] | acc
+            lblk_hi = jnp.max(jnp.where(hit2d, t_hi, bottom), axis=0,
+                              keepdims=True)
+            at_max = hit2d & (t_hi == lblk_hi)
+            lblk_lo = jnp.max(jnp.where(at_max, t_lo, bottom), axis=0,
+                              keepdims=True)
+            lacc_hi = lh_hi_ref[0, 0, c:c + 1, :]
+            lacc_lo = lh_lo_ref[0, 0, c:c + 1, :]
+            ltake = _lt(lacc_hi, lacc_lo, lblk_hi, lblk_lo)
+            lh_hi_ref[0, 0, c:c + 1, :] = jnp.where(ltake, lblk_hi, lacc_hi)
+            lh_lo_ref[0, 0, c:c + 1, :] = jnp.where(ltake, lblk_lo, lacc_lo)
+            cnt_ref[0, 0, c:c + 1, :] = cnt_ref[0, 0, c:c + 1, :] \
+                + jnp.sum(hit2d, axis=0, keepdims=True, dtype=jnp.int32)
+    out_ref[0, 0] = out_ref[0, 0] | acc
 
 
 def _pad_cov(cov: jnp.ndarray) -> jnp.ndarray:
@@ -170,168 +189,6 @@ def _pad_cov(cov: jnp.ndarray) -> jnp.ndarray:
     pad = pad.at[:, 0, :].set(jnp.uint32(0xFFFFFFFF))
     pad = pad.at[:, 1, :].set(jnp.uint32(0xFFFFFFFF))
     return pad.at[:, :, :r].set(cov)
-
-
-@functools.partial(jax.jit, static_argnames=("num_docs", "point_block",
-                                             "doc_block", "interpret",
-                                             "with_first_hits",
-                                             "with_analytics"))
-def refine_tracks_batched(pts: jnp.ndarray, rows: jnp.ndarray,
-                          cov: jnp.ndarray, num_docs: int,
-                          point_block: int = DEFAULT_POINT_BLOCK,
-                          doc_block: int = DEFAULT_DOC_BLOCK,
-                          interpret: bool = False,
-                          with_first_hits: bool = False,
-                          with_analytics: bool = False):
-    """pts [S, 4, P] uint32, rows [S, P] int32 (−1 pad), cov [C, 8, R]
-    uint32 → per-doc hit mask [S, num_docs] bool (wave-ragged doc counts
-    zero-padded to ``num_docs`` by the caller; slice per shard).
-
-    ``with_first_hits`` grows the same fused pass with a per-(doc ×
-    constraint) **first-hit** min-reduce and returns
-    ``(mask, first_hi, first_lo)`` — uint32 ``[S, C, num_docs]`` word
-    pairs, the lexicographic minimum (t_hi, t_lo) over each doc's points
-    satisfying constraint c, (0xFFFFFFFF, 0xFFFFFFFF) when none.  Ordered
-    (A-before-B) queries compare this table edge-wise; still one launch
-    per wave.
-
-    ``with_analytics`` (implies first hits) returns the full reduction
-    family ``(mask, fh_hi, fh_lo, lh_hi, lh_lo, cnt)``: **last-hit**
-    lexicographic max word pairs with a (0, 0) no-hit sentinel, and an
-    int32 ``[S, C, num_docs]`` **hit-count** table — count/dwell verdicts
-    are epilogue compares at the caller, same single launch per wave.
-    """
-    s, _, p = pts.shape
-    n_constraints = int(cov.shape[0])
-    full = jnp.int32((1 << n_constraints) - 1)
-    sent = jnp.uint32(_FH_SENT)
-
-    def table(fill, dtype=jnp.uint32):
-        return jnp.full((s, n_constraints, num_docs), fill, dtype)
-
-    def empty(out):
-        if with_analytics:
-            return (out, table(sent), table(sent), table(0), table(0),
-                    table(0, jnp.int32))
-        return (out, table(sent), table(sent)) if with_first_hits else out
-
-    if s == 0 or num_docs == 0:
-        return empty(jnp.zeros((s, num_docs), jnp.bool_))
-    if p == 0 or n_constraints == 0:
-        # no points → no constraint can hit; no constraints → vacuous truth
-        return empty(jnp.full((s, num_docs), n_constraints == 0))
-    cov = _pad_cov(cov)
-    r_pad = cov.shape[2]
-    padded_p = pl.cdiv(p, point_block) * point_block
-    padded_d = pl.cdiv(num_docs, doc_block) * doc_block
-    pts_p = jnp.zeros((s, 4, padded_p), jnp.uint32).at[:, :, :p].set(pts)
-    rows_p = jnp.full((s, padded_p), -1, jnp.int32).at[:, :p].set(rows)
-    out_shape = [jax.ShapeDtypeStruct((s, padded_d), jnp.int32)]
-    out_specs = [pl.BlockSpec((1, doc_block), lambda i, g, t: (i, g))]
-    if with_first_hits or with_analytics:
-        tbl_shape = jax.ShapeDtypeStruct((s, n_constraints, padded_d),
-                                         jnp.uint32)
-        tbl_spec = pl.BlockSpec((1, n_constraints, doc_block),
-                                lambda i, g, t: (i, 0, g))
-        out_shape += [tbl_shape, tbl_shape]
-        out_specs += [tbl_spec, tbl_spec]
-        if with_analytics:
-            cnt_shape = jax.ShapeDtypeStruct((s, n_constraints, padded_d),
-                                             jnp.int32)
-            out_shape += [tbl_shape, tbl_shape, cnt_shape]
-            out_specs += [tbl_spec, tbl_spec, tbl_spec]
-    outs = pl.pallas_call(
-        functools.partial(_refine_kernel, doc_block=doc_block,
-                          n_constraints=n_constraints),
-        grid=(s, padded_d // doc_block, padded_p // point_block),
-        in_specs=[
-            pl.BlockSpec((1, 4, point_block), lambda i, g, t: (i, 0, t)),
-            pl.BlockSpec((1, point_block), lambda i, g, t: (i, t)),
-            pl.BlockSpec((n_constraints, 8, r_pad),
-                         lambda i, g, t: (0, 0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(pts_p, rows_p, cov)
-    bits = outs[0]
-    mask = bits[:, :num_docs] == full
-    if with_analytics or with_first_hits:
-        return (mask, *(o[:, :, :num_docs] for o in outs[1:]))
-    return mask
-
-
-def _refine_kernel_multi(pts_ref, rows_ref, cov_ref, out_ref, *aux_refs,
-                         doc_block: int, n_constraints: int):
-    """Query-axis variant of ``_refine_kernel``: grid (q, s, g, t), the
-    constraint table block is the q-th query's [C, 8, R] slice, track
-    blocks are shared across queries (indexed by s alone)."""
-    g = pl.program_id(2)
-    t = pl.program_id(3)
-    sent = jnp.uint32(_FH_SENT)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-        for fh in aux_refs[:2]:                    # first-hit planes → sent
-            fh[...] = jnp.full_like(fh, sent)
-        for ref in aux_refs[2:]:                   # last-hit / count → 0
-            ref[...] = jnp.zeros_like(ref)
-
-    k_hi = pts_ref[0, 0, :][:, None]               # (T, 1) uint32
-    k_lo = pts_ref[0, 1, :][:, None]
-    t_hi = pts_ref[0, 2, :][:, None]
-    t_lo = pts_ref[0, 3, :][:, None]
-    rows = rows_ref[0, :]                          # (T,) int32
-    docs = g * doc_block + jax.lax.broadcasted_iota(
-        jnp.int32, (1, doc_block), 1)              # (1, D)
-    onehot = rows[:, None] == docs                 # (T, D) bool
-    acc = jnp.zeros((1, doc_block), jnp.int32)
-    for c in range(n_constraints):
-        lo_hi = cov_ref[0, c, 0, :][None, :]       # (1, R)
-        lo_lo = cov_ref[0, c, 1, :][None, :]
-        hi_hi = cov_ref[0, c, 2, :][None, :]
-        hi_lo = cov_ref[0, c, 3, :][None, :]
-        w0_hi = cov_ref[0, c, 4, :][None, :]
-        w0_lo = cov_ref[0, c, 5, :][None, :]
-        w1_hi = cov_ref[0, c, 6, :][None, :]
-        w1_lo = cov_ref[0, c, 7, :][None, :]
-        hit = (_ge(k_hi, k_lo, lo_hi, lo_lo)       # key in [lo, hi)
-               & _lt(k_hi, k_lo, hi_hi, hi_lo)
-               & _ge(t_hi, t_lo, w0_hi, w0_lo)     # t in [w0, w1]
-               & _le(t_hi, t_lo, w1_hi, w1_lo))
-        hit_pt = jnp.any(hit, axis=1)              # (T,)
-        hit2d = onehot & hit_pt[:, None]           # (T, D)
-        contrib = jnp.any(hit2d, axis=0)           # (D,)
-        acc = acc | jnp.left_shift(contrib[None, :].astype(jnp.int32), c)
-        if aux_refs:
-            fh_hi_ref, fh_lo_ref = aux_refs[0], aux_refs[1]
-            blk_hi = jnp.min(jnp.where(hit2d, t_hi, sent), axis=0)  # (D,)
-            at_min = hit2d & (t_hi == blk_hi[None, :])
-            blk_lo = jnp.min(jnp.where(at_min, t_lo, sent), axis=0)
-            acc_hi = fh_hi_ref[0, 0, c, :]
-            acc_lo = fh_lo_ref[0, 0, c, :]
-            take = (blk_hi < acc_hi) \
-                | ((blk_hi == acc_hi) & (blk_lo < acc_lo))
-            fh_hi_ref[0, 0, c, :] = jnp.where(take, blk_hi, acc_hi)
-            fh_lo_ref[0, 0, c, :] = jnp.where(take, blk_lo, acc_lo)
-        if len(aux_refs) > 2:
-            lh_hi_ref, lh_lo_ref, cnt_ref = aux_refs[2:]
-            zero = jnp.uint32(0)
-            lblk_hi = jnp.max(jnp.where(hit2d, t_hi, zero), axis=0)
-            at_max = hit2d & (t_hi == lblk_hi[None, :])
-            lblk_lo = jnp.max(jnp.where(at_max, t_lo, zero), axis=0)
-            lacc_hi = lh_hi_ref[0, 0, c, :]
-            lacc_lo = lh_lo_ref[0, 0, c, :]
-            ltake = (lblk_hi > lacc_hi) \
-                | ((lblk_hi == lacc_hi) & (lblk_lo > lacc_lo))
-            lh_hi_ref[0, 0, c, :] = jnp.where(ltake, lblk_hi, lacc_hi)
-            lh_lo_ref[0, 0, c, :] = jnp.where(ltake, lblk_lo, lacc_lo)
-            cnt_ref[0, 0, c, :] = cnt_ref[0, 0, c, :] \
-                + jnp.sum(hit2d.astype(jnp.int32), axis=0)
-    out_ref[...] = out_ref[...] | acc
 
 
 @functools.partial(jax.jit, static_argnames=("num_docs", "point_block",
@@ -363,6 +220,7 @@ def refine_tracks_multi(pts: jnp.ndarray, rows: jnp.ndarray,
     n_constraints = int(cov.shape[1])
     full = jnp.int32((1 << n_constraints) - 1)
     sent = jnp.uint32(_FH_SENT)
+    n_tables = 5 if with_analytics else (2 if with_first_hits else 0)
 
     def table(fill, dtype=jnp.uint32):
         return jnp.full((n_queries, s, n_constraints, num_docs), fill,
@@ -377,51 +235,85 @@ def refine_tracks_multi(pts: jnp.ndarray, rows: jnp.ndarray,
     if n_queries == 0 or s == 0 or num_docs == 0:
         return empty(jnp.zeros((n_queries, s, num_docs), jnp.bool_))
     if p == 0 or n_constraints == 0:
+        # no points → no constraint can hit; no constraints → vacuous truth
         return empty(jnp.full((n_queries, s, num_docs), n_constraints == 0))
-    cov = jnp.stack([_pad_cov(cov[q]) for q in range(n_queries)])
+    cov = _signed(jnp.stack([_pad_cov(cov[q]) for q in range(n_queries)]))
     r_pad = cov.shape[3]
     padded_p = pl.cdiv(p, point_block) * point_block
     padded_d = pl.cdiv(num_docs, doc_block) * doc_block
-    pts_p = jnp.zeros((s, 4, padded_p), jnp.uint32).at[:, :, :p].set(pts)
-    rows_p = jnp.full((s, padded_p), -1, jnp.int32).at[:, :p].set(rows)
-    out_shape = [jax.ShapeDtypeStruct((n_queries, s, padded_d), jnp.int32)]
-    out_specs = [pl.BlockSpec((1, 1, doc_block),
-                              lambda q, i, g, t: (q, i, g))]
-    if with_first_hits or with_analytics:
-        tbl_shape = jax.ShapeDtypeStruct(
-            (n_queries, s, n_constraints, padded_d), jnp.uint32)
-        tbl_spec = pl.BlockSpec((1, 1, n_constraints, doc_block),
-                                lambda q, i, g, t: (q, i, 0, g))
-        out_shape += [tbl_shape, tbl_shape]
-        out_specs += [tbl_spec, tbl_spec]
-        if with_analytics:
-            cnt_shape = jax.ShapeDtypeStruct(
-                (n_queries, s, n_constraints, padded_d), jnp.int32)
-            out_shape += [tbl_shape, tbl_shape, cnt_shape]
-            out_specs += [tbl_spec, tbl_spec, tbl_spec]
+    words = jnp.zeros((s, 8, padded_p), jnp.int32)
+    words = words.at[:, :4, :p].set(_signed(pts))
+    words = words.at[:, 4, :].set(-1).at[:, 4, :p].set(rows)
+    tbl_shape = jax.ShapeDtypeStruct(
+        (n_queries, s, n_constraints, padded_d), jnp.int32)
+    tbl_spec = pl.BlockSpec((1, 1, n_constraints, doc_block),
+                            lambda q, i, g, t: (q, i, 0, g))
     outs = pl.pallas_call(
-        functools.partial(_refine_kernel_multi, doc_block=doc_block,
+        functools.partial(_refine_kernel, doc_block=doc_block,
                           n_constraints=n_constraints),
         grid=(n_queries, s, padded_d // doc_block, padded_p // point_block),
         in_specs=[
-            pl.BlockSpec((1, 4, point_block),
-                         lambda q, i, g, t: (i, 0, t)),
-            pl.BlockSpec((1, point_block), lambda q, i, g, t: (i, t)),
+            pl.BlockSpec((1, 8, point_block), lambda q, i, g, t: (i, 0, t)),
             pl.BlockSpec((1, n_constraints, 8, r_pad),
                          lambda q, i, g, t: (q, 0, 0, 0)),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
+        out_specs=[pl.BlockSpec((1, 1, 1, doc_block),
+                                lambda q, i, g, t: (q, i, 0, g))]
+        + [tbl_spec] * n_tables,
+        out_shape=[jax.ShapeDtypeStruct((n_queries, s, 1, padded_d),
+                                        jnp.int32)]
+        + [tbl_shape] * n_tables,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(pts_p, rows_p, cov)
-    bits = outs[0]
-    mask = bits[:, :, :num_docs] == full
+    )(words, cov)
+    mask = outs[0][:, :, 0, :num_docs] == full
+    if not n_tables:
+        return mask
+    tables = [o[..., :num_docs] for o in outs[1:]]
+    words_out = tuple(_unsigned(o) for o in tables[:4])
+    return (mask, *words_out, *tables[4:])
+
+
+@functools.partial(jax.jit, static_argnames=("num_docs", "point_block",
+                                             "doc_block", "interpret",
+                                             "with_first_hits",
+                                             "with_analytics"))
+def refine_tracks_batched(pts: jnp.ndarray, rows: jnp.ndarray,
+                          cov: jnp.ndarray, num_docs: int,
+                          point_block: int = DEFAULT_POINT_BLOCK,
+                          doc_block: int = DEFAULT_DOC_BLOCK,
+                          interpret: bool = False,
+                          with_first_hits: bool = False,
+                          with_analytics: bool = False):
+    """pts [S, 4, P] uint32, rows [S, P] int32 (−1 pad), cov [C, 8, R]
+    uint32 → per-doc hit mask [S, num_docs] bool (wave-ragged doc counts
+    zero-padded to ``num_docs`` by the caller; slice per shard): the
+    one-query case of :func:`refine_tracks_multi`.
+
+    ``with_first_hits`` grows the same fused pass with a per-(doc ×
+    constraint) **first-hit** min-reduce and returns
+    ``(mask, first_hi, first_lo)`` — uint32 ``[S, C, num_docs]`` word
+    pairs, the lexicographic minimum (t_hi, t_lo) over each doc's points
+    satisfying constraint c, (0xFFFFFFFF, 0xFFFFFFFF) when none.  Ordered
+    (A-before-B) queries compare this table edge-wise; still one launch
+    per wave.
+
+    ``with_analytics`` (implies first hits) returns the full reduction
+    family ``(mask, fh_hi, fh_lo, lh_hi, lh_lo, cnt)``: **last-hit**
+    lexicographic max word pairs with a (0, 0) no-hit sentinel, and an
+    int32 ``[S, C, num_docs]`` **hit-count** table — count/dwell verdicts
+    are epilogue compares at the caller, same single launch per wave.
+    """
+    out = refine_tracks_multi(pts, rows, cov[None], num_docs,
+                              point_block=point_block, doc_block=doc_block,
+                              interpret=interpret,
+                              with_first_hits=with_first_hits,
+                              with_analytics=with_analytics)
     if with_analytics or with_first_hits:
-        return (mask, *(o[..., :num_docs] for o in outs[1:]))
-    return mask
+        return tuple(o[0] for o in out)
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("num_docs", "point_block",

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["ssm_scan"]
 
 DEFAULT_CHUNK = 256
@@ -70,7 +68,7 @@ def ssm_scan(a: jnp.ndarray, bx: jnp.ndarray, chunk: int = DEFAULT_CHUNK,
         out_specs=pl.BlockSpec((1, c, D_p), lambda b, t: (b, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, L_p, D_p), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, D_p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(a_p, bx_p)

@@ -9,16 +9,23 @@ device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh",
            "make_exec_mesh", "default_exec_partitions"]
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding code here
+    relies on the compiler propagating shardings, not on explicit axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod; multi-pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -26,7 +33,7 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_exec_mesh(partitions: int = 0):
@@ -40,7 +47,7 @@ def make_exec_mesh(partitions: int = 0):
     """
     n = len(jax.devices())
     size = min(max(1, int(partitions)) or n, n) if partitions else n
-    return jax.make_mesh((max(size, 1),), ("part",))
+    return _auto_mesh((max(size, 1),), ("part",))
 
 
 def default_exec_partitions() -> int:

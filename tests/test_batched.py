@@ -177,19 +177,24 @@ def test_engine_parity_on_ragged_shards(ragged_catalog, qi, wave):
     assert rn.profile.rows_selected == rj.profile.rows_selected
 
 
-def test_flume_wave_error_does_not_abort_siblings(ragged_catalog, tmp_path,
-                                                  monkeypatch):
-    """A wave that errors outright must not discard completed waves'
-    checkpoints; its shards fall through to the per-shard machinery."""
+def _crash_first_wave(monkeypatch, exc):
     import repro.exec.flume as flume_mod
     real = flume_mod.run_wave_task
 
     def flaky(db, plan, sids, *a, **kw):
         if 0 in list(sids):
-            raise RuntimeError("injected wave crash")
+            raise exc
         return real(db, plan, sids, *a, **kw)
 
     monkeypatch.setattr(flume_mod, "run_wave_task", flaky)
+
+
+def test_flume_wave_error_does_not_abort_siblings(ragged_catalog, tmp_path,
+                                                  monkeypatch):
+    """A wave lost to a machine failure must not discard completed waves'
+    checkpoints; its shards fall through to the per-shard machinery."""
+    from repro.exec.failures import TaskFailure
+    _crash_first_wave(monkeypatch, TaskFailure("injected wave crash"))
     q = QUERIES[0]
     fl = FlumeEngine(ragged_catalog, ckpt_dir=str(tmp_path), max_workers=4,
                      backend="numpy", wave=3)
@@ -199,6 +204,19 @@ def test_flume_wave_error_does_not_abort_siblings(ragged_catalog, tmp_path,
     assert_identical(ref.batch, res.batch)
     # 4 shards via surviving waves + 3 via the per-shard fallback
     assert fl.stats["tasks_run"] == 7
+
+
+def test_flume_wave_bug_raises_after_siblings_commit(ragged_catalog,
+                                                     tmp_path, monkeypatch):
+    """Any other wave error is a bug, not a lost machine: it surfaces
+    (never a silent per-shard re-run), after the sibling waves commit
+    their checkpoints, so a rerun recovers them."""
+    _crash_first_wave(monkeypatch, ValueError("injected wave bug"))
+    fl = FlumeEngine(ragged_catalog, ckpt_dir=str(tmp_path), max_workers=4,
+                     backend="numpy", wave=3)
+    with pytest.raises(ValueError, match="injected wave bug"):
+        fl.collect(QUERIES[0])
+    assert fl.stats["tasks_run"] == 4
 
 
 def test_flume_wave_path_parity(ragged_catalog, tmp_path):

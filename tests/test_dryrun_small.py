@@ -21,8 +21,9 @@ from dataclasses import replace
 from repro.configs.base import SHAPES, ShapeConfig, get_config
 from repro.ml.model import ModelBundle, TrainConfig
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_local_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(2, 4)
 assert len(jax.devices()) == 8
 
 cfg = get_config("qwen1_5_0_5b").reduced()

@@ -374,7 +374,7 @@ def test_prefetch_stages_next_wave_before_wave_done(dense_catalog,
     eng.collect(AGG_FLOW)                      # warm
     be.trace_events = []
     eng.collect(AGG_FLOW)
-    ev = be.trace_events
+    ev = [e for e in be.trace_events if e[0] in ("prefetch", "wave_done")]
     be.trace_events = None
     kinds = [e[0] for e in ev]
     pp = exec_pplan(dense_catalog.get("FusedAgg").num_shards, be)

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs, SHAPES, shape_cells
 from repro.ml.transformer import LM
+from repro.launch.mesh import make_local_mesh
 from repro.ml.model import ModelBundle, TrainConfig, input_specs
 
 ARCHS = list_archs()
@@ -52,7 +53,7 @@ def test_forward_shapes_no_nans(arch):
 def test_train_step(arch):
     """One optimizer step must run and produce finite loss + updates."""
     cfg = _reduced(arch)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     mb = ModelBundle(cfg, mesh,
                      train_cfg=TrainConfig(loss_chunk=16, remat="none"))
     params = mb.lm.init(jax.random.key(0))

@@ -71,11 +71,8 @@ def test_ef_compression_error_feedback():
 def test_compressed_psum_shard_map():
     """int8 all-gather + local reduce ≈ fp32 psum (within quant error)."""
     import jax
-    from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:   # jax ≤ 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
     from repro.ml.optim import compressed_psum
 
     devs = jax.devices()
@@ -85,12 +82,8 @@ def test_compressed_psum_shard_map():
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(8,)).astype(np.float32))
 
-    try:
-        f = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
-                      in_specs=P(), out_specs=P(), check_vma=False)
-    except TypeError:     # jax ≤ 0.4.x spells it check_rep
-        f = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
-                      in_specs=P(), out_specs=P(), check_rep=False)
+    f = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
+                  in_specs=P(), out_specs=P(), check_vma=False)
     got = f(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(x), atol=2e-2,
                                rtol=2e-2)

@@ -15,10 +15,7 @@ from repro.ml.transformer import LM
 def mesh16():
     # Shape-rule checks don't need real devices — abstract mesh suffices.
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:   # jax ≤ 0.4.x: shape_tuple of (name, size) pairs
-        return AbstractMesh((("data", 16), ("model", 16)))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _specs_for(arch, mesh):
