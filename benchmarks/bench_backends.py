@@ -9,9 +9,7 @@ per-primitive wave launches) — and the report shows per-query wall time,
 speedup, kernel-launch counts, and a byte-level parity verdict against
 the numpy per-shard oracle — the contract every future lowering (GPU,
 sharded meshes) must keep.  Timing blocks on the last device output
-before the clock stops (jax dispatch is async).  With
-``benchmarks.run --profile`` each query row adds a per-stage
-(upload/probe/refine/compact/agg) device-time breakdown.
+before the clock stops (jax dispatch is async).
 
 On CPU the jax backend resolves to the ``reference`` kernel impl, so the
 timing column measures dispatch overhead, not TPU speedup; run with
@@ -22,17 +20,13 @@ any suite reports a false one (the CI bench smoke gate).
 """
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 from repro.exec import AdHocEngine, get_backend
-from repro.kernels import fused as fused_kernels
 from repro.fdb.index import bitmap_from_ids, bitmap_full
 from repro.kernels import ops as kernel_ops
 
-from .queries import (QUERIES, build_catalog, q_variability, sync,
-                      time_best)
+from .queries import QUERIES, build_catalog, q_variability, time_best
 
 __all__ = ["run", "batches_identical"]
 
@@ -85,11 +79,6 @@ def _bench_primitives(rows, print_fn):
 
 def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
     rows: list = []
-    # REPRO_EXEC_PROFILE=1 (benchmarks.run --profile): the fused pipeline
-    # runs its stages eagerly with per-stage device sync so each query row
-    # carries a "stages" timing breakdown (diagnostic mode — the fused
-    # single-dispatch timing above is the real number)
-    profile = os.environ.get("REPRO_EXEC_PROFILE") == "1"
     _bench_primitives(rows, print_fn)
 
     cat = build_catalog(scale=scale)
@@ -100,7 +89,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
     for qname, (cities, months) in QUERIES.items():
         flow = q_variability(cities, months)
         results, times = {}, {}
-        stages, launches = None, 0
+        launches = 0
         for bname, eng in engines.items():
             if bname == "jax":
                 kernel_ops.reset_launch_counts()
@@ -115,13 +104,6 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
             # total; with REPRO_EXEC_FUSED=0 it is ⌈shards/wave⌉ per
             # primitive
             launches = sum(kernel_ops.launch_counts().values()) // 3
-            if profile:
-                # per-stage device ms (upload/probe/refine/compact/agg)
-                # for ONE post-warm collect, so compile time stays out
-                fused_kernels.reset_stage_times()
-                sync(eng.collect(flow))
-                stages = {k: round(v, 3)
-                          for k, v in fused_kernels.stage_times().items()}
         parity = batches_identical(results["numpy"].batch,
                                    results["jax"].batch) \
             and results["numpy"].profile.rows_selected \
@@ -132,7 +114,6 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
             "name": f"backend_e2e_{qname}",
             "us_per_call": round(times["jax"] * 1e3, 1),
             "parity": 1 if parity else 0,
-            **({"stages": stages} if stages else {}),
             "derived": (f"numpy={times['numpy']:.1f}ms "
                         f"jax={times['jax']:.1f}ms "
                         f"speedup={speedup:.2f}x "
@@ -140,8 +121,7 @@ def run(scale: float = 0.5, print_fn=print, raise_on_mismatch: bool = True):
                         f"launches={launches} "
                         f"shards={n_shards} wave={wave} "
                         f"parity={'OK' if parity else 'MISMATCH'}")})
-        print_fn(f"  {qname}: {rows[-1]['derived']}"
-                 + (f" stages={stages}" if stages else ""))
+        print_fn(f"  {qname}: {rows[-1]['derived']}")
     rows.append({"name": "backend_parity_all",
                  "us_per_call": "",
                  "parity": 1 if all_parity else 0,

@@ -63,17 +63,8 @@ def main() -> None:
                          "(wall time + parity bit)")
     ap.add_argument("--json-dir", default=".",
                     help="directory for --json output files")
-    ap.add_argument("--profile", action="store_true",
-                    help="run the fused wave pipeline stage-by-stage with "
-                         "per-stage device sync and add a per-stage "
-                         "(upload/probe/refine/compact/agg) ms breakdown "
-                         "to each backend query row (diagnostic: stages "
-                         "run eagerly, so wall times are not the fused "
-                         "single-dispatch numbers)")
     args = ap.parse_args()
     enable_compile_cache()
-    if args.profile:
-        os.environ["REPRO_EXEC_PROFILE"] = "1"
 
     # one bench per registry entry (benchmarks/suites.py): --only here,
     # check_regression.py --suite, and the Makefile all read the same table

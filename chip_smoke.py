@@ -385,17 +385,12 @@ def phase_partitions(cat, flows):
         flow, kind = flows[name]
         out = {}
         for p, ses in sessions.items():
-            backend = ses.engine.backend
             want_waves, want_merges = fused_launches(ses, flow, kind)
-            backend.trace_events = []
             res, ms, counts = timed_collect(ses, flow)
             check_fused(counts, want_waves, want_merges)
-            devices = sorted({d for ev in backend.trace_events
-                              if ev[0] == "wave_devices" for d in ev[1]})
-            backend.trace_events = None
             out[p] = res
             log(f"  {name} P={p}: {ms:.1f} ms, fused={want_waves} "
-                f"merge={want_merges}, wave outputs on {devices}")
+                f"merge={want_merges}")
         if kind == "agg":
             err = aggregates_close(out[1], out[4])
             log(f"  {name}: P=4 ≡ P=1 within tolerance "

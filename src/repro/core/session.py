@@ -28,7 +28,7 @@ class Session:
     def __init__(self, engine=None, catalog=None, backend=None,
                  config=None):
         """``config`` is an :class:`repro.exec.ExecConfig` bundling the
-        execution knobs (backend/wave/partitions/fused/profile) when no
+        execution knobs (backend/wave/partitions/fused) when no
         explicit engine is supplied; the legacy ``backend`` kwarg
         ("numpy", "jax", or an ExecBackend instance) remains as a shim."""
         if engine is None:

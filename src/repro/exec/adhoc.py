@@ -27,6 +27,7 @@ from ..core.planner import PartitionPlan, Plan, plan_flow
 from ..fdb.columnar import ColumnBatch
 from ..fdb.fdb import FDb, Shard, _build_shard_indexes
 from ..fdb.schema import DOUBLE, INT, STRING, Schema
+from ..spans import next_query_id, span
 from .backend import as_backend
 from .batched import (merge_partition_partials, partition_waves,
                       resolve_partition_plan, run_wave_task, wave_size)
@@ -115,45 +116,59 @@ class AdHocEngine:
 
     # ------------------------------------------------------------- public
     def collect(self, flow: Flow, fault_plan: Optional[FaultPlan] = None,
-                num_servers: Optional[int] = None) -> QueryResult:
-        t0 = time.perf_counter()
-        plan = plan_flow(flow, self.catalog)
-        # execute against the snapshot the planner pinned: for streaming
-        # sources a concurrent append swaps the catalog's current view,
-        # and a re-resolve here could tear the query across generations
-        db = plan.db if plan.db is not None else self.catalog.get(plan.source)
-        # device-resident columns: one-time put per FDb (no-op on host
-        # backends; for a streaming snapshot only new delta buffers
-        # upload — shared sealed shards are already resident)
-        self.backend.prime_fdb(db)
+                num_servers: Optional[int] = None,
+                query_id: Optional[int] = None) -> QueryResult:
+        """Plan and run ``flow``.  ``query_id`` is the number its spans
+        carry (``repro.spans``); a query gets a fresh one unless it
+        entered the program elsewhere, as a server's query does."""
+        q = next_query_id() if query_id is None else query_id
+        with span("query", query=q):
+            t0 = time.perf_counter()
+            with span("plan", query=q):
+                plan = plan_flow(flow, self.catalog)
+            # execute against the snapshot the planner pinned: for
+            # streaming sources a concurrent append swaps the catalog's
+            # current view, and a re-resolve here could tear the query
+            # across generations
+            db = plan.db if plan.db is not None \
+                else self.catalog.get(plan.source)
+            # device-resident columns: one-time put per FDb (no-op on host
+            # backends; for a streaming snapshot only new delta buffers
+            # upload — shared sealed shards are already resident)
+            with span("prime", query=q):
+                self.backend.prime_fdb(db)
 
-        # Broadcast side of hash joins: run the right flow first (recursive
-        # query), index it by the right key — the paper's broadcast join.
-        tables: Dict[int, CollectedTable] = {}
-        for op in plan.server_ops:
-            if isinstance(op, JoinOp):
-                rres = self.collect(op.right, fault_plan=fault_plan)
-                if not isinstance(op.right_key, FieldRef):
-                    raise TypeError("join right_key must be a field")
-                tables[id(op)] = rres.to_dict(op.right_key.path)
+            # Broadcast side of hash joins: run the right flow first
+            # (recursive query), index it by the right key — the paper's
+            # broadcast join.
+            tables: Dict[int, CollectedTable] = {}
+            for op in plan.server_ops:
+                if isinstance(op, JoinOp):
+                    rres = self.collect(op.right, fault_plan=fault_plan)
+                    if not isinstance(op.right_key, FieldRef):
+                        raise TypeError("join right_key must be a field")
+                    tables[id(op)] = rres.to_dict(op.right_key.path)
 
-        want = min(len(plan.shard_ids), num_servers or self.num_servers)
-        grant = self.catalog.resources.acquire(want)
-        profile = QueryProfile(source=plan.source,
-                               shards_total=len(plan.shard_ids))
-        pplan = self._partition_plan(plan, profile, fault_plan)
-        try:
-            partials = self._run_servers(db, plan, tables, grant, profile,
-                                         fault_plan, pplan)
-        finally:
-            self.catalog.resources.release(grant)
+            want = min(len(plan.shard_ids), num_servers or self.num_servers)
+            grant = self.catalog.resources.acquire(want)
+            profile = QueryProfile(source=plan.source,
+                                   shards_total=len(plan.shard_ids))
+            pplan = self._partition_plan(plan, profile, fault_plan)
+            try:
+                partials = self._run_servers(db, plan, tables, grant,
+                                             profile, fault_plan, pplan, q)
+            finally:
+                self.catalog.resources.release(grant)
 
-        batch = self._mixer(plan, partials, profile,
-                            premerged=merge_partition_partials(
-                                db, plan, partials, self.backend, pplan))
-        profile.exec_ms = (time.perf_counter() - t0) * 1e3
-        self.profile_log.append(profile.record())
-        return QueryResult(batch, profile, plan)
+            with span("merge", query=q):
+                premerged = merge_partition_partials(db, plan, partials,
+                                                     self.backend, pplan)
+            with span("mix", query=q):
+                batch = self._mixer(plan, partials, profile,
+                                    premerged=premerged)
+            profile.exec_ms = (time.perf_counter() - t0) * 1e3
+            self.profile_log.append(profile.record())
+            return QueryResult(batch, profile, plan)
 
     def save(self, flow: Flow, name: str, num_shards: int = 8,
              schema: Optional[Schema] = None, **kw) -> FDb:
@@ -187,16 +202,17 @@ class AdHocEngine:
                                       fault_plan, profile)
 
     def _run_partition_wave(self, pplan, pi, db, plan, sids, nxt, tables,
-                            fault_plan):
+                            fault_plan, query_id=None):
         with self.backend.partition_context(pi, pplan.num_partitions):
             return run_wave_task(db, plan, sids, tables, self.catalog,
                                  fault_plan, backend=self.backend,
                                  prefetch_sids=nxt,
                                  fused=self.config.fused,
-                                 profile=self.config.profile)
+                                 query_id=query_id)
 
     def _run_servers(self, db, plan, tables, grant, profile, fault_plan,
-                     pplan: Optional[PartitionPlan] = None
+                     pplan: Optional[PartitionPlan] = None,
+                     query_id: Optional[int] = None
                      ) -> List[_ShardPartial]:
         """Per-partition waves of shards through the batched backend
         seam; shards whose fault check trips at wave start fall back to
@@ -218,7 +234,7 @@ class AdHocEngine:
                              else None))
         with ThreadPoolExecutor(max_workers=grant) as pool:
             futs = [pool.submit(self._run_partition_wave, pplan, pi, db,
-                                plan, w, nxt, tables, fault_plan)
+                                plan, w, nxt, tables, fault_plan, query_id)
                     for pi, w, nxt in subs]
             for f in as_completed(futs):
                 done, failed = f.result()

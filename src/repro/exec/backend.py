@@ -91,6 +91,7 @@ import numpy as np
 
 from ..fdb.index import (bitmap_from_ids, bitmap_stack, ids_from_bitmap,
                          mask_from_bitmap)
+from ..spans import span
 from .device_cache import device_form, host_form
 from .refine import (FIRST_HIT_NONE, LAST_HIT_NONE, pack_constraints,
                      pack_constraints_multi, pack_track_points,
@@ -344,7 +345,7 @@ class ExecBackend:
             np.nonzero(overlap)[0].astype(np.int64), n_docs)
 
     def run_wave_fused(self, shards, probes, refine=None, agg=None,
-                       prefetch_shards=None, profile=None):
+                       prefetch_shards=None, query_id=None):
         """Whole-wave probe → refine → compact → (segment-agg) as one
         logical dispatch.  Returns ``(n_cands, ids_list, seg)``: per-shard
         pre-refine candidate counts, selected doc ids, and — when ``agg``
@@ -355,7 +356,9 @@ class ExecBackend:
 
         This base implementation is the loop-over-stages oracle the fused
         overrides must match byte-for-byte; ``prefetch_shards`` is a hint
-        only (no-op on host backends)."""
+        only (no-op on host backends), and ``query_id`` the number an
+        override's spans carry (``repro.spans``)."""
+        del query_id
         shards = list(shards)
         if not shards:
             return [], [], ([] if agg is not None else None)
@@ -537,18 +540,11 @@ class JaxBackend(ExecBackend):
     def __init__(self, impl: Optional[str] = None):
         import jax  # container ships the jax_pallas toolchain
         import jax.numpy as jnp
-        from ..kernels import fused as fused_mod
         from ..kernels import ops
         from .device_cache import DeviceCache
         self._jax, self._jnp, self._ops = jax, jnp, ops
-        self._fused = fused_mod
         self.impl = impl
         self.device_cache = DeviceCache(jax)
-        #: when set to a list, the fused path appends ("prefetch", n) /
-        #: ("wave_done", shard_ids) markers — the prefetch-ordering tests'
-        #: evidence that wave k+1 staged before wave k finished — and
-        #: ("wave_devices", names): where each fused wave's outputs live
-        self.trace_events: Optional[list] = None
         # weak: a collected FDb drops out, so a new FDb reusing the same
         # address still primes, and a finalizer evicts its buffers.
         # Buffers are refcounted across FDbs — StreamingFDb snapshots
@@ -1381,7 +1377,7 @@ class JaxBackend(ExecBackend):
         return facts, offsets, codes_dev, tuple(vals_dev), total
 
     def run_wave_fused(self, shards, probes, refine=None, agg=None,
-                       prefetch_shards=None, profile=None):
+                       prefetch_shards=None, query_id=None):
         """One fused dispatch for the whole wave (``kernels.fused``), or
         ``None`` to decline to the per-primitive path: a refine spec with
         zero or >30 constraints, a shard without a packed track, or a
@@ -1389,7 +1385,6 @@ class JaxBackend(ExecBackend):
         already covers that case).  ``prefetch_shards`` — the next wave's
         shards — are staged *before* this wave's outputs sync back to the
         host, overlapping upload with compute."""
-        import time as _time
         shards = list(shards)
         probes = [list(ps) for ps in probes]
         if not shards:
@@ -1432,58 +1427,54 @@ class JaxBackend(ExecBackend):
         if refine is not None and max(p.shape[1] for p, _ in packs) == 0:
             return None
         impl = self._impl()
-        if profile is None:     # explicit config wins over the env knob
-            profile = os.environ.get("REPRO_EXEC_PROFILE") == "1"
-        t_up = _time.perf_counter()
-        k = 1 + max((len(ps) for ps in probes), default=0)
-        stack = np.zeros((len(shards), k, w), dtype=np.uint32)
-        for i, (f, ps) in enumerate(zip(fulls, probes)):
-            stack[i, 0, :f.size] = f
-            for j, b in enumerate(ps):
-                stack[i, j + 1, :b.size] = b
-            for j in range(len(ps) + 1, k):
-                stack[i, j, :f.size] = f
-        probe_dev = self._jnp.asarray(stack)
-        ns_dev = self._jnp.asarray(np.asarray(ns, dtype=np.int32))
-        pts_stack = rows_stack = cov_dev = None
-        if refine is not None:
-            pts_stack, rows_stack = self._refine_stack(shards, packs,
-                                                       refine.path)
-            cov_dev = self._jnp.asarray(pack_constraints(cons))
-        codes_dev, vals_dev, total = None, (), 0
-        facts, offsets = [], None
-        if agg is not None:
-            facts, offsets, codes_dev, vals_dev, total = \
-                self._agg_stacks(shards, agg, impl, n_max)
-        if profile:
-            self._jax.block_until_ready(probe_dev)
-            self._fused.record_stage(
-                "upload", (_time.perf_counter() - t_up) * 1e3)
+        with span("stack", query=query_id):
+            k = 1 + max((len(ps) for ps in probes), default=0)
+            stack = np.zeros((len(shards), k, w), dtype=np.uint32)
+            for i, (f, ps) in enumerate(zip(fulls, probes)):
+                stack[i, 0, :f.size] = f
+                for j, b in enumerate(ps):
+                    stack[i, j + 1, :b.size] = b
+                for j in range(len(ps) + 1, k):
+                    stack[i, j, :f.size] = f
+            probe_dev = self._jnp.asarray(stack)
+            ns_dev = self._jnp.asarray(np.asarray(ns, dtype=np.int32))
+            pts_stack = rows_stack = cov_dev = None
+            if refine is not None:
+                pts_stack, rows_stack = self._refine_stack(shards, packs,
+                                                           refine.path)
+                cov_dev = self._jnp.asarray(pack_constraints(cons))
+            codes_dev, vals_dev, total = None, (), 0
+            facts, offsets = [], None
+            if agg is not None:
+                facts, offsets, codes_dev, vals_dev, total = \
+                    self._agg_stacks(shards, agg, impl, n_max)
         minmax = tuple(getattr(agg, "minmax", ()) or ()) \
             if agg is not None else ()
-        cand, sel_idx, sel_counts, segs = self._ops.run_wave_fused(
-            probe_dev, ns_dev, pts_stack, rows_stack, cov_dev, codes_dev,
-            vals_dev, num_docs=n_max, edges=edges, min_counts=mcs,
-            dwells=dws, total_groups=total, impl=impl, profile=profile,
-            minmax=minmax)
-        if self.trace_events is not None:
-            self.trace_events.append(
-                ("wave_devices", tuple(str(d) for d in sel_idx.devices())))
+        with span("dispatch", query=query_id):
+            cand, sel_idx, sel_counts, segs = self._ops.run_wave_fused(
+                probe_dev, ns_dev, pts_stack, rows_stack, cov_dev,
+                codes_dev, vals_dev, num_docs=n_max, edges=edges,
+                min_counts=mcs, dwells=dws, total_groups=total, impl=impl,
+                minmax=minmax)
         # stage wave k+1's buffers before wave k's outputs sync to host
         if prefetch_shards:
             self.prefetch_wave(prefetch_shards, refine, agg)
-        idx_h = np.asarray(sel_idx)
-        counts_h = np.asarray(sel_counts)
-        n_cands = [int(c) for c in np.asarray(cand)]
+        with span("sync", query=query_id):
+            idx_h = np.asarray(sel_idx)
+            counts_h = np.asarray(sel_counts)
+            cand_h = np.asarray(cand)
+            segs_h = [[np.asarray(a) for a in st] for st in (segs or [])]
+        n_cands = [int(c) for c in cand_h]
         ids_list = [idx_h[i, :int(counts_h[i])].astype(np.int64)
                     for i in range(len(shards))]
-        seg = None
-        if agg is not None:
+        if agg is None:
+            return n_cands, ids_list, None
+        with span("finalize", query=query_id):
             # slots are (count, sum, sumsq) triples, or 5-tuples with the
             # per-group min/max planes appended for flagged value slots
             slot_host = []
-            for st in (segs or []):
-                slot = (np.rint(np.asarray(st[0])).astype(np.int64),
+            for st in segs_h:
+                slot = (np.rint(st[0]).astype(np.int64),
                         np.asarray(st[1], dtype=np.float64),
                         np.asarray(st[2], dtype=np.float64))
                 if len(st) == 5:
@@ -1570,36 +1561,39 @@ class JaxBackend(ExecBackend):
                     for _ in range(n_q)]
         if has_refine and max(p.shape[1] for p, _ in packs) == 0:
             return None
-        k = 1 + max((len(ps) for probes in probes_multi for ps in probes),
-                    default=0)
-        stack = np.zeros((n_q, len(shards), k, w), dtype=np.uint32)
-        for q, probes in enumerate(probes_multi):
-            for i, (f, ps) in enumerate(zip(fulls, probes)):
-                stack[q, i, 0, :f.size] = f
-                for j, b in enumerate(ps):
-                    stack[q, i, j + 1, :b.size] = b
-                for j in range(len(ps) + 1, k):
-                    stack[q, i, j, :f.size] = f
-        probe_dev = self._jnp.asarray(stack)
-        ns_dev = self._jnp.asarray(np.asarray(ns, dtype=np.int32))
-        pts_stack = rows_stack = cov_dev = None
-        edges_multi = tuple(() for _ in range(n_q))
-        if has_refine:
-            pts_stack, rows_stack = self._refine_stack(shards, packs, path)
-            cov_dev = self._jnp.asarray(pack_constraints_multi(cons_list))
-            edges_multi = tuple(tuple(tuple(e) for e in r.edges)
-                                for r in refines)
-        cand, sel_idx, sel_counts = self._ops.run_wave_fused_multi(
-            probe_dev, ns_dev, pts_stack, rows_stack, cov_dev,
-            num_docs=n_max, edges_multi=edges_multi,
-            min_counts_multi=mcs_multi, dwells_multi=dws_multi,
-            impl=self._impl())
+        with span("stack", n=n_q):
+            k = 1 + max((len(ps) for probes in probes_multi for ps in probes),
+                        default=0)
+            stack = np.zeros((n_q, len(shards), k, w), dtype=np.uint32)
+            for q, probes in enumerate(probes_multi):
+                for i, (f, ps) in enumerate(zip(fulls, probes)):
+                    stack[q, i, 0, :f.size] = f
+                    for j, b in enumerate(ps):
+                        stack[q, i, j + 1, :b.size] = b
+                    for j in range(len(ps) + 1, k):
+                        stack[q, i, j, :f.size] = f
+            probe_dev = self._jnp.asarray(stack)
+            ns_dev = self._jnp.asarray(np.asarray(ns, dtype=np.int32))
+            pts_stack = rows_stack = cov_dev = None
+            edges_multi = tuple(() for _ in range(n_q))
+            if has_refine:
+                pts_stack, rows_stack = self._refine_stack(shards, packs, path)
+                cov_dev = self._jnp.asarray(pack_constraints_multi(cons_list))
+                edges_multi = tuple(tuple(tuple(e) for e in r.edges)
+                                    for r in refines)
+        with span("dispatch", n=n_q):
+            cand, sel_idx, sel_counts = self._ops.run_wave_fused_multi(
+                probe_dev, ns_dev, pts_stack, rows_stack, cov_dev,
+                num_docs=n_max, edges_multi=edges_multi,
+                min_counts_multi=mcs_multi, dwells_multi=dws_multi,
+                impl=self._impl())
         if prefetch_shards:
             self.prefetch_wave(prefetch_shards,
                                refines[0] if has_refine else None)
-        cand_h = np.asarray(cand)
-        idx_h = np.asarray(sel_idx)
-        counts_h = np.asarray(sel_counts)
+        with span("sync", n=n_q):
+            cand_h = np.asarray(cand)
+            idx_h = np.asarray(sel_idx)
+            counts_h = np.asarray(sel_counts)
         out = []
         for q in range(n_q):
             n_cands = [int(c) for c in cand_h[q]]
@@ -1616,21 +1610,20 @@ class JaxBackend(ExecBackend):
         shards = list(shards)
         if not shards:
             return
-        if self.trace_events is not None:
-            self.trace_events.append(("prefetch", len(shards)))
-        n_max = max(sh.n for sh in shards)
-        if n_max == 0:
-            return
-        if refine is not None:
-            cons = list(refine.constraints)
-            if cons and len(cons) <= 30:
-                packs = [self._track_pack(sh.batch, refine.path)
-                         for sh in shards]
-                if all(p is not None for p, _ in packs) and \
-                        max(p.shape[1] for p, _ in packs) > 0:
-                    self._refine_stack(shards, packs, refine.path)
-        if agg is not None:
-            self._agg_stacks(shards, agg, self._impl(), n_max)
+        with span("prefetch"):
+            n_max = max(sh.n for sh in shards)
+            if n_max == 0:
+                return
+            if refine is not None:
+                cons = list(refine.constraints)
+                if cons and len(cons) <= 30:
+                    packs = [self._track_pack(sh.batch, refine.path)
+                             for sh in shards]
+                    if all(p is not None for p, _ in packs) and \
+                            max(p.shape[1] for p, _ in packs) > 0:
+                        self._refine_stack(shards, packs, refine.path)
+            if agg is not None:
+                self._agg_stacks(shards, agg, self._impl(), n_max)
 
     # ---------------------------------------------------- partition layer
     def partition_context(self, part: int, num_parts: int):

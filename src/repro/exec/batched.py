@@ -53,6 +53,7 @@ from ..core.planner import (PartitionPlan, Plan, num_partitions,
                             partition_shards)
 from ..fdb.fdb import FDb
 from ..fdb.index import mask_from_bitmap
+from ..spans import span
 from .backend import as_backend
 from .failures import FaultPlan, TaskFailure
 from .processors import (AggPartial, aggregate_produce_batched, apply_limit,
@@ -254,7 +255,7 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
                   stage: str = "server", backend=None,
                   prefetch_sids: Optional[Sequence[int]] = None,
                   fused: Optional[bool] = None,
-                  profile: Optional[bool] = None
+                  query_id: Optional[int] = None
                   ) -> Tuple[List[ShardPartial], List[int]]:
     """Run one wave of shard tasks through the batched backend seam.
 
@@ -263,6 +264,7 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
     per-shard retry path.  ``prefetch_sids`` — the next wave's shard ids —
     lets a fused backend stage that wave's device buffers while this one
     computes (double-buffered upload; ignored on host backends).
+    ``query_id`` is the number the wave's spans carry (``repro.spans``).
     """
     backend = as_backend(backend)
     failed: List[int] = []
@@ -282,7 +284,8 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
     shards = [db.shards[sid] for sid in live]
     # probe bitmaps stay host-built (index lookups over host postings) so
     # the fused path's launch count is exactly the fused dispatches
-    probe_bms = [[p.run(sh) for p in plan.probes] for sh in shards]
+    with span("probe", query=query_id):
+        probe_bms = [[p.run(sh) for p in plan.probes] for sh in shards]
 
     # ---- fused whole-wave dispatch: probe → refine → compact → (agg) in
     # ONE launch when the backend and plan shape allow it
@@ -296,15 +299,12 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
         fused_out = backend.run_wave_fused(
             shards, probe_bms,
             plan.refines[0] if plan.refines else None, fused_agg,
-            prefetch_shards=pre, profile=profile)
+            prefetch_shards=pre, query_id=query_id)
         if fused_out is None:                 # backend declined this wave
             fused_agg = None
 
     if fused_out is not None:
         n_cands, ids_list, seg = fused_out
-        trace = getattr(backend, "trace_events", None)
-        if trace is not None:
-            trace.append(("wave_done", tuple(live)))
     else:
         # ---- per-primitive path: one launch per primitive per wave
         seg = None
@@ -330,19 +330,21 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
     # read set is all-dense (fused_agg_plan guarantees it)
     if fused_agg is not None:
         partials = []
-        for i, (sid, sh, ids, n_cand) in enumerate(
-                zip(live, shards, ids_list, n_cands)):
-            paths = [p for p in plan.source_paths if p in sh.batch.columns]
-            if not paths:
-                paths = sh.batch.paths()
-            nbytes = int(ids.size) * sum(
-                int(sh.batch[p].values.dtype.itemsize) for p in paths)
-            part = ShardPartial(shard_id=sid, rows_scanned=sh.n,
-                                rows_selected=n_cand, bytes_read=nbytes)
-            uniq, slots = seg[i]
-            part.agg = _fused_agg_finalize(fused_agg, uniq, slots)
-            part.seg = (uniq, slots)
-            partials.append(part)
+        with span("finalize", query=query_id):
+            for i, (sid, sh, ids, n_cand) in enumerate(
+                    zip(live, shards, ids_list, n_cands)):
+                paths = [p for p in plan.source_paths
+                         if p in sh.batch.columns]
+                if not paths:
+                    paths = sh.batch.paths()
+                nbytes = int(ids.size) * sum(
+                    int(sh.batch[p].values.dtype.itemsize) for p in paths)
+                part = ShardPartial(shard_id=sid, rows_scanned=sh.n,
+                                    rows_selected=n_cand, bytes_read=nbytes)
+                uniq, slots = seg[i]
+                part.agg = _fused_agg_finalize(fused_agg, uniq, slots)
+                part.seg = (uniq, slots)
+                partials.append(part)
         io_each = (time.perf_counter() - t1) * 1e3 / len(live)
         cpu_each = (time.perf_counter() - t0) * 1e3 / len(live)
         for part in partials:
@@ -353,41 +355,43 @@ def run_wave_task(db: FDb, plan: Plan, sids: Sequence[int],
     # ---- selective column read (device-resident buffers when primed)
     partials: List[ShardPartial] = []
     batches = []
-    for sid, sh, ids, n_cand in zip(live, shards, ids_list, n_cands):
-        paths = [p for p in plan.source_paths if p in sh.batch.columns]
-        if not paths:
-            paths = sh.batch.paths()
-        batch = backend.gather_columns(sh.batch, paths, ids)
-        partials.append(ShardPartial(shard_id=sid, rows_scanned=sh.n,
-                                     rows_selected=n_cand,
-                                     bytes_read=batch.nbytes()))
-        batches.append(batch)
+    with span("gather", query=query_id):
+        for sid, sh, ids, n_cand in zip(live, shards, ids_list, n_cands):
+            paths = [p for p in plan.source_paths if p in sh.batch.columns]
+            if not paths:
+                paths = sh.batch.paths()
+            batch = backend.gather_columns(sh.batch, paths, ids)
+            partials.append(ShardPartial(shard_id=sid, rows_scanned=sh.n,
+                                         rows_selected=n_cand,
+                                         bytes_read=batch.nbytes()))
+            batches.append(batch)
     t2 = time.perf_counter()
 
-    # ---- residual filter: masks host-evaluated, compacted in one launch
-    if plan.residual is not None:
-        keeps = backend.compact_masks(
-            [predicate_mask(b, plan.residual) for b in batches])
-        batches = [b.gather(k) for b, k in zip(batches, keeps)]
-    batches = [run_record_ops(b, plan.server_ops, catalog, tables,
-                              backend=backend) for b in batches]
+    with span("mix", query=query_id):
+        # ---- residual filter: masks host-evaluated, compacted in one launch
+        if plan.residual is not None:
+            keeps = backend.compact_masks(
+                [predicate_mask(b, plan.residual) for b in batches])
+            batches = [b.gather(k) for b, k in zip(batches, keeps)]
+        batches = [run_record_ops(b, plan.server_ops, catalog, tables,
+                                  backend=backend) for b in batches]
 
-    # ---- tail: wave-batched aggregation, or per-shard presort/limit
-    if plan.mixer_ops and isinstance(plan.mixer_ops[0], AggregateOp):
-        aggs = aggregate_produce_batched(batches, plan.mixer_ops[0].spec,
-                                         backend)
-        for part, agg in zip(partials, aggs):
-            part.agg = agg
-    else:
-        presort = (len(plan.mixer_ops) >= 2
-                   and isinstance(plan.mixer_ops[0], SortOp)
-                   and isinstance(plan.mixer_ops[1], LimitOp))
-        for part, batch in zip(partials, batches):
-            pre = batch
-            if presort:
-                pre = apply_limit(apply_sort(pre, plan.mixer_ops[0]),
-                                  plan.mixer_ops[1].k)
-            part.batch = pre
+        # ---- tail: wave-batched aggregation, or per-shard presort/limit
+        if plan.mixer_ops and isinstance(plan.mixer_ops[0], AggregateOp):
+            aggs = aggregate_produce_batched(batches, plan.mixer_ops[0].spec,
+                                             backend)
+            for part, agg in zip(partials, aggs):
+                part.agg = agg
+        else:
+            presort = (len(plan.mixer_ops) >= 2
+                       and isinstance(plan.mixer_ops[0], SortOp)
+                       and isinstance(plan.mixer_ops[1], LimitOp))
+            for part, batch in zip(partials, batches):
+                pre = batch
+                if presort:
+                    pre = apply_limit(apply_sort(pre, plan.mixer_ops[0]),
+                                      plan.mixer_ops[1].k)
+                part.batch = pre
 
     # profile attribution: wave phases are shared work, split evenly
     io_each = (t2 - t1) * 1e3 / len(live)

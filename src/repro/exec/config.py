@@ -11,7 +11,6 @@ backend      ``REPRO_EXEC_BACKEND``     execution backend ("numpy" | "jax" |
 wave         ``REPRO_EXEC_WAVE``        shards per batched dispatch wave
 partitions   ``REPRO_EXEC_PARTITIONS``  execution partitions per query
 fused        ``REPRO_EXEC_FUSED``       single fused dispatch per wave
-profile      ``REPRO_EXEC_PROFILE``     per-stage device sync + timing
 ===========  =========================  =====================================
 
 Resolution order is **explicit field > environment variable > default** for
@@ -31,13 +30,12 @@ from dataclasses import dataclass, replace
 from typing import Any, Optional
 
 __all__ = ["ExecConfig", "BACKEND_ENV", "WAVE_ENV", "PARTITIONS_ENV",
-           "FUSED_ENV", "PROFILE_ENV"]
+           "FUSED_ENV"]
 
 BACKEND_ENV = "REPRO_EXEC_BACKEND"
 WAVE_ENV = "REPRO_EXEC_WAVE"
 PARTITIONS_ENV = "REPRO_EXEC_PARTITIONS"
 FUSED_ENV = "REPRO_EXEC_FUSED"
-PROFILE_ENV = "REPRO_EXEC_PROFILE"
 
 
 @dataclass(frozen=True)
@@ -46,7 +44,6 @@ class ExecConfig:
     wave: Optional[int] = None
     partitions: Optional[int] = None
     fused: Optional[bool] = None
-    profile: Optional[bool] = None
 
     # -- construction -------------------------------------------------------
     def fill(self, **legacy) -> "ExecConfig":
@@ -76,8 +73,3 @@ class ExecConfig:
         if self.fused is not None:
             return bool(self.fused)
         return os.environ.get(FUSED_ENV, "") != "0"
-
-    def resolved_profile(self) -> bool:
-        if self.profile is not None:
-            return bool(self.profile)
-        return os.environ.get(PROFILE_ENV) == "1"

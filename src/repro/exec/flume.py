@@ -122,8 +122,7 @@ class FlumeEngine:
                     return run_wave_task(
                         db, plan, sids, tables, self.catalog, None,
                         stage="server", backend=self.backend,
-                        prefetch_sids=nxt, fused=self.config.fused,
-                        profile=self.config.profile)
+                        prefetch_sids=nxt, fused=self.config.fused)
         partials = self._run_stage(
             stage="server", job_dir=job_dir, task_ids=plan.shard_ids,
             fn=lambda sid: run_shard_task(db, plan, sid, tables,

@@ -121,6 +121,7 @@ def bitmap_intersect_batched(stack: jnp.ndarray,
     nblk = s2.shape[2]
     out, cnt = pl.pallas_call(
         _intersect_batched_kernel,
+        name="bitmap_intersect_batched",
         grid=(s, nblk),
         in_specs=[pl.BlockSpec((1, k, 1, 8, lanes),
                                lambda i, j: (i, 0, j, 0, 0))],

@@ -81,6 +81,7 @@ def mask_prefix_sum_batched(masks: jnp.ndarray, block: int = DEFAULT_BLOCK,
     nblk = m2.shape[1]
     pos, totals = pl.pallas_call(
         _scan_batched_kernel,
+        name="mask_prefix_sum_batched",
         grid=(s, nblk),
         in_specs=[pl.BlockSpec((1, 1, 8, block // 8),
                                lambda i, j: (i, j, 0, 0))],

@@ -30,18 +30,14 @@ the pre-refine candidate counts, ``sel_idx``/``sel_counts`` the compacted
 survivor row ids, and ``segs`` a list of ``(count, sum, sumsq)`` triples
 over the wave-global group space (``None`` without aggregation).
 
-``profile=True`` runs the same stage math eagerly with a device sync after
-each stage and records wall-clock per stage into :func:`stage_times` —
-the ``--profile`` bench flag's data source.  This module never imports
-``kernels.ops`` (ops wraps *it* and owns launch counting).
+This module never imports ``kernels.ops`` (ops wraps *it* and owns launch
+counting).
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import threading
-import time
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,37 +51,11 @@ from . import refine as _refine
 from . import segment_agg as _seg
 
 __all__ = ["run_wave_fused", "run_wave_fused_multi", "postings_bitmap",
-           "segment_hll", "record_stage", "stage_times",
-           "reset_stage_times"]
+           "segment_hll"]
 
 
 # --------------------------------------------------------------------------
-# Per-stage wall-clock (bench --profile); engines run in worker threads.
-# --------------------------------------------------------------------------
-
-_STAGE_MS: Dict[str, float] = {}
-_STAGE_LOCK = threading.Lock()
-
-
-def record_stage(name: str, ms: float) -> None:
-    """Accumulate ``ms`` milliseconds of wall-clock under stage ``name``."""
-    with _STAGE_LOCK:
-        _STAGE_MS[name] = _STAGE_MS.get(name, 0.0) + ms
-
-
-def stage_times() -> Dict[str, float]:
-    """Snapshot of accumulated per-stage milliseconds since last reset."""
-    with _STAGE_LOCK:
-        return dict(_STAGE_MS)
-
-
-def reset_stage_times() -> None:
-    with _STAGE_LOCK:
-        _STAGE_MS.clear()
-
-
-# --------------------------------------------------------------------------
-# Stage bodies (shared by the jitted composition and the profiled path)
+# Stage bodies of the jitted composition
 # --------------------------------------------------------------------------
 
 def _probe_stage(impl: str, probe_stack):
@@ -234,40 +204,11 @@ def _fused_fn(impl: str, num_docs: int,
     return jax.jit(fn)
 
 
-def _profiled(impl, probe_stack, ns, pts, rows, cov, codes, vals,
-              num_docs, edges, total_groups, has_refine, minmax=(),
-              min_counts=(), dwells=()):
-    """Same math, eager stage-by-stage with a sync + timer per stage."""
-    t = time.perf_counter
-    t0 = t()
-    mask = _mask_stage(_probe_stage(impl, probe_stack), ns, num_docs)
-    cand = jax.block_until_ready(mask.sum(axis=1).astype(jnp.int32))
-    t1 = t()
-    record_stage("probe", (t1 - t0) * 1e3)
-    if has_refine:
-        mask = jax.block_until_ready(
-            mask & _refine_stage(impl, pts, rows, cov, num_docs, edges,
-                                 min_counts, dwells))
-        t2 = t()
-        record_stage("refine", (t2 - t1) * 1e3)
-        t1 = t2
-    sel_idx, sel_counts = jax.block_until_ready(_compact_stage(impl, mask))
-    t2 = t()
-    record_stage("compact", (t2 - t1) * 1e3)
-    segs = None
-    if total_groups > 0:
-        segs = jax.block_until_ready(
-            _agg_stage(impl, mask, codes, vals, total_groups, minmax))
-        record_stage("agg", (t() - t2) * 1e3)
-    return cand, sel_idx, sel_counts, segs
-
-
 def run_wave_fused(probe_stack, ns, pts=None, rows=None, cov=None,
                    codes=None, vals=(), *, num_docs: int,
                    edges=(), min_counts=(), dwells=(),
                    total_groups: int = 0,
-                   impl: str = "reference", profile: bool = False,
-                   minmax=()):
+                   impl: str = "reference", minmax=()):
     """Run one wave through the fused pipeline (see module docstring).
     ``minmax`` flags which value slots also reduce per-group min/max
     (5-tuple partials); ``min_counts``/``dwells`` apply per-constraint
@@ -285,10 +226,6 @@ def run_wave_fused(probe_stack, ns, pts=None, rows=None, cov=None,
     ctx = jax.enable_x64(True) if impl == "reference" \
         else contextlib.nullcontext()
     with ctx:
-        if profile:
-            return _profiled(impl, probe_stack, ns, pts, rows, cov,
-                             codes, vals, num_docs, edges, total_groups,
-                             has_refine, minmax, min_counts, dwells)
         return _fused_fn(impl, num_docs, edges, total_groups,
                          has_refine, minmax, min_counts,
                          dwells)(probe_stack, ns, pts, rows, cov, codes,

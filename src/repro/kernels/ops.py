@@ -248,8 +248,7 @@ def refine_tracks_multi(pts, rows, cov, num_docs: int,
 def run_wave_fused(probe_stack, ns, pts=None, rows=None, cov=None,
                    codes=None, vals=(), *, num_docs: int, edges=(),
                    min_counts=(), dwells=(), total_groups: int = 0,
-                   impl: Optional[str] = None, profile: bool = False,
-                   minmax=()):
+                   impl: Optional[str] = None, minmax=()):
     """Whole-wave fused pipeline (probe → refine → compact → segment-agg)
     in ONE dispatch — see ``kernels.fused``.  Counts as a single launch:
     the fused path's ⌈shards/wave⌉ *total*-dispatch contract hangs off
@@ -265,7 +264,7 @@ def run_wave_fused(probe_stack, ns, pts=None, rows=None, cov=None,
                                  vals, num_docs=num_docs, edges=edges,
                                  min_counts=min_counts, dwells=dwells,
                                  total_groups=total_groups, impl=impl,
-                                 profile=profile, minmax=minmax)
+                                 minmax=minmax)
 
 
 def run_wave_fused_multi(probe_stacks, ns, pts=None, rows=None, cov=None, *,

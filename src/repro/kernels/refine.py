@@ -251,6 +251,7 @@ def refine_tracks_multi(pts: jnp.ndarray, rows: jnp.ndarray,
     outs = pl.pallas_call(
         functools.partial(_refine_kernel, doc_block=doc_block,
                           n_constraints=n_constraints),
+        name="refine_tracks_multi",
         grid=(n_queries, s, padded_d // doc_block, padded_p // point_block),
         in_specs=[
             pl.BlockSpec((1, 8, point_block), lambda q, i, g, t: (i, 0, t)),

@@ -75,6 +75,7 @@ def segment_agg(group_ids: jnp.ndarray, values: jnp.ndarray,
     n_grp_blocks = padded_g // group_block
     out = pl.pallas_call(
         functools.partial(_seg_kernel, group_block=group_block),
+        name="segment_agg",
         grid=(n_grp_blocks, n_row_blocks),
         in_specs=[
             pl.BlockSpec((1, row_block), lambda g, t: (0, t)),

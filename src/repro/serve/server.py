@@ -54,7 +54,7 @@ import time
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from types import SimpleNamespace
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 from ..core.flow import AggregateOp, Flow, JoinOp
 from ..core.planner import Plan, plan_flow
@@ -65,6 +65,7 @@ from ..exec.batched import (fused_enabled, partition_waves,
 from ..exec.processors import aggregate_produce_batched, run_record_ops
 from ..exec.task import ShardPartial
 from ..fdb.index import mask_from_bitmap
+from ..spans import next_query_id, span
 from .result_cache import ResultCache
 
 __all__ = ["QueryServer", "ServerBusy"]
@@ -75,11 +76,14 @@ class ServerBusy(RuntimeError):
 
 
 class _Pending:
-    __slots__ = ("flow", "future", "plan", "key", "cache_key")
+    __slots__ = ("flow", "future", "plan", "key", "cache_key", "query",
+                 "submitted")
 
-    def __init__(self, flow: Flow, future: Future):
+    def __init__(self, flow: Flow, future: Future, query: int):
         self.flow = flow
         self.future = future
+        self.query = query                 # the number its spans carry
+        self.submitted = time.perf_counter()
         self.plan: Optional[Plan] = None
         self.key = None                    # coalescing compatibility key
         self.cache_key = None
@@ -114,7 +118,8 @@ class QueryServer:
         self._stats = {"admitted": 0, "rejected": 0, "served": 0,
                        "coalesced_queries": 0, "coalesced_batches": 0,
                        "fallback_queries": 0, "cache_hits": 0,
-                       "cache_errors": 0}
+                       "cache_errors": 0, "dequeued": 0,
+                       "queue_wait_ms": 0.0}
         self._thread = threading.Thread(target=self._loop,
                                         name="repro-serve-scheduler",
                                         daemon=True)
@@ -127,14 +132,15 @@ class QueryServer:
         :class:`QueryResult`.  Raises :class:`ServerBusy` when the
         pending queue is at capacity."""
         fut: Future = Future()
-        with self._cv:
+        q = next_query_id()
+        with span("submit", query=q), self._cv:
             if self._closed:
                 raise RuntimeError("QueryServer is closed")
             if len(self._pending) >= self.max_pending:
                 self._stats["rejected"] += 1
                 raise ServerBusy(
                     f"admission queue full ({self.max_pending} pending)")
-            self._pending.append(_Pending(flow, fut))
+            self._pending.append(_Pending(flow, fut, q))
             self._stats["admitted"] += 1
             self._cv.notify()
         return fut
@@ -153,11 +159,18 @@ class QueryServer:
         with self._cv:
             batch = list(self._pending)
             self._pending.clear()
+            self._dequeued(batch)
         if batch:
             self._serve_batch(batch)
         return len(batch)
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
+        """Counters since start: ``served`` queries and how
+        (``coalesced_queries`` in ``coalesced_batches``,
+        ``fallback_queries``, ``cache_hits``), admission (``admitted``,
+        ``rejected``, ``pending`` now), and ``queue_wait_ms``, the sum of
+        the milliseconds each of the ``dequeued`` queries waited between
+        its submit and the scheduler taking it off the queue."""
         with self._cv:
             out = dict(self._stats)
             out["pending"] = len(self._pending)
@@ -196,12 +209,15 @@ class QueryServer:
                     continue
                 batch = list(self._pending)
                 self._pending.clear()
+                self._dequeued(batch)
             # a short tick lets near-simultaneous submits join this batch
             if self.tick_s > 0 and len(batch) < self.max_coalesce:
                 time.sleep(self.tick_s)
                 with self._cv:
+                    n0 = len(batch)
                     while self._pending and len(batch) < 4 * self.max_coalesce:
                         batch.append(self._pending.popleft())
+                    self._dequeued(batch[n0:])
             try:
                 self._serve_batch(batch)
             except Exception as e:                 # defensive: never die
@@ -209,41 +225,51 @@ class QueryServer:
                     if not p.future.done():
                         p.future.set_exception(e)
 
+    def _dequeued(self, taken: List[_Pending]) -> None:
+        """Count the queue wait of queries just taken off ``_pending``
+        (the caller holds ``_cv``)."""
+        now = time.perf_counter()
+        self._stats["dequeued"] += len(taken)
+        self._stats["queue_wait_ms"] += sum(
+            (now - p.submitted) * 1e3 for p in taken)
+
     def _serve_batch(self, batch: List[_Pending]) -> None:
-        groups: Dict[tuple, List[_Pending]] = {}
-        singles: List[_Pending] = []
-        for p in batch:
-            try:
-                p.plan = plan_flow(p.flow, self.engine.catalog)
-            except Exception as e:
-                p.future.set_exception(e)
-                continue
-            self._watch_live(p.plan.source)
-            if self._cache_get(p):
-                continue
-            p.key = self._compat_key(p.plan)
-            if p.key is None:
-                singles.append(p)
-            else:
-                groups.setdefault(p.key, []).append(p)
-        for key, grp in groups.items():
-            for i in range(0, len(grp), self.max_coalesce):
-                chunk = grp[i:i + self.max_coalesce]
-                if len(chunk) == 1:
-                    singles.extend(chunk)
-                    continue
+        with span("serve.batch", n=len(batch)):
+            groups: Dict[tuple, List[_Pending]] = {}
+            singles: List[_Pending] = []
+            for p in batch:
                 try:
-                    self._run_group(chunk)
-                except Exception:
-                    # coalesced execution is an optimization, never a
-                    # correctness risk: re-run each query solo
-                    singles.extend(c for c in chunk if not c.future.done())
-        for p in singles:
-            self._run_single(p)
+                    with span("plan", query=p.query):
+                        p.plan = plan_flow(p.flow, self.engine.catalog)
+                except Exception as e:
+                    p.future.set_exception(e)
+                    continue
+                self._watch_live(p.plan.source)
+                if self._cache_get(p):
+                    continue
+                p.key = self._compat_key(p.plan)
+                if p.key is None:
+                    singles.append(p)
+                else:
+                    groups.setdefault(p.key, []).append(p)
+            for key, grp in groups.items():
+                for i in range(0, len(grp), self.max_coalesce):
+                    chunk = grp[i:i + self.max_coalesce]
+                    if len(chunk) == 1:
+                        singles.extend(chunk)
+                        continue
+                    try:
+                        self._run_group(chunk)
+                    except Exception:
+                        # coalesced execution is an optimization, never a
+                        # correctness risk: re-run each query solo
+                        singles.extend(c for c in chunk if not c.future.done())
+            for p in singles:
+                self._run_single(p)
 
     def _run_single(self, p: _Pending) -> None:
         try:
-            res = self.engine.collect(p.flow)
+            res = self.engine.collect(p.flow, query_id=p.query)
             self._cache_put(p, res)
             # stats land before the future resolves, so a client that has
             # its result also sees it counted
@@ -349,7 +375,8 @@ class QueryServer:
         # append between planning and this wave must not swap the data
         db = plans[0].db if plans[0].db is not None \
             else engine.catalog.get(plans[0].source)
-        backend.prime_fdb(db)
+        with span("prime", n=len(chunk)):
+            backend.prime_fdb(db)
         shard_ids = list(plans[0].shard_ids)
         # the coalesced dispatch rides the same partition layer as the
         # single-query engines: waves form *within* each partition and
@@ -378,7 +405,9 @@ class QueryServer:
             # gather is byte-identical by the seam contract (selection by
             # row index) and its cost is linear in gathered bytes, not in
             # per-call device-dispatch overhead
-            gb = ExecBackend.gather_columns(backend, sh.batch, paths, ids)
+            with span("gather", query=chunk[qi].query):
+                gb = ExecBackend.gather_columns(backend, sh.batch, paths,
+                                                ids)
             part = ShardPartial(shard_id=sid, rows_scanned=sh.n,
                                 rows_selected=n_cand,
                                 bytes_read=gb.nbytes())
@@ -389,10 +418,11 @@ class QueryServer:
             with ThreadPoolExecutor(max_workers=grant) as pool:
                 for pi, wave_sids, nxt in subs:
                     shards = [db.shards[s] for s in wave_sids]
-                    probes_multi = [
-                        [self._probe_bitmaps(db, pl, sid, sh)
-                         for sid, sh in zip(wave_sids, shards)]
-                        for pl in plans]
+                    with span("probe", n=len(plans)):
+                        probes_multi = [
+                            [self._probe_bitmaps(db, pl, sid, sh)
+                             for sid, sh in zip(wave_sids, shards)]
+                            for pl in plans]
                     pre = [db.shards[s] for s in nxt] if nxt else None
                     out = None
                     cfg = getattr(self.engine, "config", None)
@@ -425,28 +455,29 @@ class QueryServer:
 
         results = []
         for p, pl, pairs in zip(chunk, plans, per_query):
-            parts = [part for part, _ in pairs]
-            batches = [run_record_ops(gb, pl.server_ops, engine.catalog,
-                                      None, backend=backend)
-                       for _, gb in pairs]
-            if pl.mixer_ops and isinstance(pl.mixer_ops[0], AggregateOp):
-                aggs = aggregate_produce_batched(
-                    batches, pl.mixer_ops[0].spec, backend)
-                for part, agg in zip(parts, aggs):
-                    part.agg = agg
-            else:
-                for part, gb in zip(parts, batches):
-                    part.batch = gb
-            profile = QueryProfile(source=pl.source,
-                                   shards_total=len(shard_ids),
-                                   shards_done=len(parts))
-            for part in parts:
-                profile.rows_scanned += part.rows_scanned
-                profile.rows_selected += part.rows_selected
-                profile.bytes_read += part.bytes_read
-            batch = engine._mixer(pl, parts, profile)
-            profile.exec_ms = (time.perf_counter() - t0) * 1e3
-            engine.profile_log.append(profile.record())
+            with span("mix", query=p.query):
+                parts = [part for part, _ in pairs]
+                batches = [run_record_ops(gb, pl.server_ops, engine.catalog,
+                                          None, backend=backend)
+                           for _, gb in pairs]
+                if pl.mixer_ops and isinstance(pl.mixer_ops[0], AggregateOp):
+                    aggs = aggregate_produce_batched(
+                        batches, pl.mixer_ops[0].spec, backend)
+                    for part, agg in zip(parts, aggs):
+                        part.agg = agg
+                else:
+                    for part, gb in zip(parts, batches):
+                        part.batch = gb
+                profile = QueryProfile(source=pl.source,
+                                       shards_total=len(shard_ids),
+                                       shards_done=len(parts))
+                for part in parts:
+                    profile.rows_scanned += part.rows_scanned
+                    profile.rows_selected += part.rows_selected
+                    profile.bytes_read += part.bytes_read
+                batch = engine._mixer(pl, parts, profile)
+                profile.exec_ms = (time.perf_counter() - t0) * 1e3
+                engine.profile_log.append(profile.record())
             results.append((p, QueryResult(batch, profile, pl)))
         # every query finalized — count the batch, then resolve futures,
         # so a client that has its result also sees it counted

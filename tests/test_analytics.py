@@ -335,22 +335,17 @@ def test_exec_config_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_EXEC_BACKEND", raising=False)
     monkeypatch.delenv("REPRO_EXEC_WAVE", raising=False)
     monkeypatch.delenv("REPRO_EXEC_FUSED", raising=False)
-    monkeypatch.delenv("REPRO_EXEC_PROFILE", raising=False)
     # defaults
     cfg = ExecConfig()
     assert type(cfg.resolve_backend()).__name__ == "NumpyBackend"
     assert cfg.resolved_fused() is True
-    assert cfg.resolved_profile() is False
     # env fallback when the field is unset
     monkeypatch.setenv("REPRO_EXEC_FUSED", "0")
-    monkeypatch.setenv("REPRO_EXEC_PROFILE", "1")
     monkeypatch.setenv("REPRO_EXEC_WAVE", "5")
     assert ExecConfig().resolved_fused() is False
-    assert ExecConfig().resolved_profile() is True
     assert ExecConfig().resolve_wave() == 5
     # explicit field beats the env
     assert ExecConfig(fused=True).resolved_fused() is True
-    assert ExecConfig(profile=False).resolved_profile() is False
     assert ExecConfig(wave=2).resolve_wave() == 2
     # legacy kwargs fill only unset fields
     filled = ExecConfig(wave=4).fill(wave=9, backend="jax")

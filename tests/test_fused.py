@@ -22,6 +22,8 @@ from repro.geo import AreaTree, mercator as M
 from repro.kernels import ops
 from repro.tess import Tesseract
 
+from profile_capture import captured_spans
+
 RNG = np.random.default_rng(23)
 
 #: word-boundary shard sizes — 32-bit bitmap words must not leak pad docs
@@ -360,33 +362,34 @@ def test_fused_env_kill_switch(dense_catalog, monkeypatch):
 
 def test_prefetch_stages_next_wave_before_wave_done(dense_catalog,
                                                     monkeypatch,
-                                                    exec_pplan):
+                                                    exec_pplan, tmp_path):
     monkeypatch.setenv(FUSED_ENV, "1")
     """The fused dispatch hands wave k+1's buffers to the device while
-    wave k computes: a ("prefetch", n) trace marker lands before wave k's
-    ("wave_done", ...) marker, for every non-final wave.  Prefetch runs
-    within each execution partition, so the expected counts follow the
-    PartitionPlan: Σ_p waves_p dispatches, Σ_p max(waves_p − 1, 0)
-    prefetches (a single-wave partition stages nothing ahead)."""
+    wave k computes: in a profile of one query, a ``warpflow.prefetch``
+    span ends before wave k's ``warpflow.sync`` span (its outputs' copy
+    to the host) starts, for every non-final wave.  Prefetch runs within
+    each execution partition, so the expected counts follow the
+    PartitionPlan: Σ_p waves_p syncs, Σ_p max(waves_p − 1, 0) prefetches
+    (a single-wave partition stages nothing ahead)."""
     be = JaxBackend()
     be.prime_fdb(dense_catalog.get("FusedAgg"))
     eng = AdHocEngine(dense_catalog, num_servers=1, backend=be, wave=3)
     eng.collect(AGG_FLOW)                      # warm
-    be.trace_events = []
-    eng.collect(AGG_FLOW)
-    ev = [e for e in be.trace_events if e[0] in ("prefetch", "wave_done")]
-    be.trace_events = None
-    kinds = [e[0] for e in ev]
+    with captured_spans(tmp_path) as got:
+        eng.collect(AGG_FLOW)
+    ev = [s for s in got if s.name in ("prefetch", "sync")]
+    kinds = [s.name for s in ev]
     pp = exec_pplan(dense_catalog.get("FusedAgg").num_shards, be)
     part_waves = [math.ceil(s / 3) for s in pp.sizes() if s]
-    assert kinds.count("wave_done") == sum(part_waves)
+    assert kinds.count("sync") == sum(part_waves)
     assert kinds.count("prefetch") == sum(w - 1 for w in part_waves)
-    # wave k's prefetch-of-(k+1) precedes wave k's own completion marker
+    # wave k's prefetch-of-(k+1) precedes wave k's own sync
     if part_waves and part_waves[0] > 1:
-        assert kinds[0] == "prefetch" and kinds[1] == "wave_done"
-    for i, e in enumerate(ev):
-        if e[0] == "prefetch":
-            assert ev[i + 1][0] == "wave_done"
+        assert kinds[0] == "prefetch" and kinds[1] == "sync"
+    for i, s in enumerate(ev):
+        if s.name == "prefetch":
+            assert ev[i + 1].name == "sync"
+            assert s.end <= ev[i + 1].start
 
 
 def test_keyed_cache_reused_and_separate(dense_catalog, dense_db,

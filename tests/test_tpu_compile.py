@@ -10,6 +10,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -141,12 +143,23 @@ def _fused_cases():
             "fused_multi": multi, "postings_bitmap": postings}
 
 
+#: the ``pallas_call`` names each fused program holds: the device trace
+#: knows a stage by them (``chipbench/roofline/`` reads ``segment_agg``
+#: and ``refine_tracks_multi``)
+_SELECT = ("bitmap_intersect_batched", "mask_prefix_sum_batched")
+_KERNELS = {"fused_agg": _SELECT + ("segment_agg",),
+            "fused_multi": _SELECT + ("refine_tracks_multi",),
+            "postings_bitmap": ()}
+
+
 @pytest.mark.parametrize("name", sorted(_fused_cases()))
 def test_fused_program_compiles_for_v5e(name, shape):
     fn, args = _fused_cases()[name](shape)
     hlo = fn.lower(*args).compile().as_text()
     if name != "postings_bitmap":             # pure XLA, no kernel
         assert "tpu_custom_call" in hlo
+    for kernel in _KERNELS.get(name, _SELECT + ("refine_tracks_multi",)):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", hlo), kernel
 
 
 def test_merge_compiles_on_four_chips(topo):
