@@ -106,6 +106,19 @@ def _has_red(min_counts, dwells) -> bool:
             or (dwells is not None and any(d is not None for d in dwells)))
 
 
+def _refine_grid_meta(n_queries: int, pts_stack,
+                      num_docs: int) -> Dict[str, int]:
+    """``dispatch`` span meta of a fused wave's refine stage: the grid
+    steps its banded walk takes (``refine_steps``) and those of the dense
+    doc-block × point-block grid (``refine_dense``); none without one."""
+    if pts_stack is None:
+        return {}
+    from ..kernels.refine import grid_steps
+    s, _, p = pts_stack.shape
+    steps, dense = grid_steps(n_queries, s, p, num_docs)
+    return {"refine_steps": steps, "refine_dense": dense}
+
+
 def _segment_minmax_host(codes: np.ndarray, values: np.ndarray,
                          num_groups: int) -> Tuple[np.ndarray, np.ndarray]:
     """Host per-group (min, max) float64 — the oracle for the fused agg
@@ -1450,7 +1463,8 @@ class JaxBackend(ExecBackend):
                     self._agg_stacks(shards, agg, impl, n_max)
         minmax = tuple(getattr(agg, "minmax", ()) or ()) \
             if agg is not None else ()
-        with span("dispatch", query=query_id):
+        with span("dispatch", query=query_id,
+                  **_refine_grid_meta(1, pts_stack, n_max)):
             cand, sel_idx, sel_counts, segs = self._ops.run_wave_fused(
                 probe_dev, ns_dev, pts_stack, rows_stack, cov_dev,
                 codes_dev, vals_dev, num_docs=n_max, edges=edges,
@@ -1581,7 +1595,8 @@ class JaxBackend(ExecBackend):
                 cov_dev = self._jnp.asarray(pack_constraints_multi(cons_list))
                 edges_multi = tuple(tuple(tuple(e) for e in r.edges)
                                     for r in refines)
-        with span("dispatch", n=n_q):
+        with span("dispatch", n=n_q,
+                  **_refine_grid_meta(n_q, pts_stack, n_max)):
             cand, sel_idx, sel_counts = self._ops.run_wave_fused_multi(
                 probe_dev, ns_dev, pts_stack, rows_stack, cov_dev,
                 num_docs=n_max, edges_multi=edges_multi,

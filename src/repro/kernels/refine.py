@@ -21,15 +21,30 @@ Input packing (all integer words, so the pass is exact on any impl):
     (win_lo, win_hi) window, all as (hi, lo) word pairs.  Padding slots use
     an empty range (lo = 2^64−1, hi = 0) and never hit.
 
-The kernel walks a ``(query, shard, doc-block, point-block)`` grid: per
-point block it evaluates all C constraints against the R ranges on the
-VPU, reduces hits per doc through the one-hot ``rows == doc_iota`` compare,
+The kernel walks a ``(query, shard, step)`` grid over a **banded**
+schedule: doc block g (docs [g·128, (g+1)·128)) only meets the point
+blocks that hold its own points, so each shard's steps are the
+(doc block, point block) pairs whose bands overlap, listed with doc blocks
+nondecreasing — the merge path of the CSR layout.  Per step it evaluates
+all C constraints against the R ranges on the VPU for one point block,
+reduces hits per doc through the one-hot ``rows == doc_iota`` compare,
 and OR-accumulates a **per-doc constraint bitset** (bit c set ⇔ some point
 satisfied constraint c).  A doc passes iff its bitset is full — computed in
 the jit epilogue.  A wave of shards (ragged P and doc counts zero-padded)
 rides the shard axis and Q coalesced queries the query axis, so a wave
 costs **one** launch, mirroring ``compact_batched``; the single-query and
 single-shard entry points are the Q=1 / S=1 cases of the same call.
+
+Precondition of the banded walk: each shard's ``rows`` is nondecreasing
+over its points and followed only by a ``-1`` tail pad — the layout
+``exec.refine.pack_track_points`` (``row_splits`` expanded) and the wave
+stacks padded with ``-1`` give.  The schedule is derived inside the jit
+from ``rows`` (:func:`_band_schedule`) and reaches the index maps as
+scalar prefetch; its length per shard is the static bound
+:func:`grid_steps` counts, so every world of one shape compiles the same
+program.  Every doc block, empty or padding, has at least one step, the
+first of its run, where its outputs are initialised; the bound's spare
+steps at the tail repeat the last block and compute nothing.
 
 On the device the point words and doc ids travel as one int32 ``[8, T]``
 tile per point block (rows: key hi/lo, time hi/lo, doc id, 3 zero rows),
@@ -59,6 +74,7 @@ the existing ⌈shards/wave⌉ dispatches, never launches.
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +82,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["refine_tracks", "refine_tracks_batched", "refine_tracks_multi",
-           "DEFAULT_POINT_BLOCK", "DEFAULT_DOC_BLOCK"]
+           "grid_steps", "DEFAULT_POINT_BLOCK", "DEFAULT_DOC_BLOCK"]
 
 DEFAULT_POINT_BLOCK = 512
 DEFAULT_DOC_BLOCK = 128
@@ -102,17 +118,81 @@ def _unsigned(x):
     return jax.lax.bitcast_convert_type(x, jnp.uint32) ^ jnp.uint32(_SIGN)
 
 
-def _refine_kernel(words_ref, cov_ref, out_ref, *aux_refs,
-                   doc_block: int, n_constraints: int):
-    """One (query, shard, doc-block, point-block) grid step.  ``aux_refs``
-    are the first-hit (hi, lo) planes, then last-hit (hi, lo) and the hit
-    count under analytics; all words are in signed order."""
-    g = pl.program_id(2)
-    t = pl.program_id(3)
+# A schedule step is one int32 word in SMEM: flags in bits 0–1, the point
+# block from bit 2, the doc block above it (``_step_shift``).
+_INIT, _COMPUTE = 1, 2           # first step of a doc block's run; work
+_FLAG_BITS = 2
+
+
+def grid_steps(q: int, s: int, p: int, num_docs: int,
+               point_block: int = DEFAULT_POINT_BLOCK,
+               doc_block: int = DEFAULT_DOC_BLOCK) -> Tuple[int, int]:
+    """(banded, dense) grid steps of one :func:`refine_tracks_multi`
+    launch over Q queries × S shards of P points and ``num_docs`` docs:
+    the banded schedule's static length against the full doc-block ×
+    point-block product it replaces.  Bands split the points in doc
+    order, so consecutive bands share at most one point block and an
+    empty band takes one step: a shard needs at most ⌈P/T⌉ + ⌈D/B⌉ − 1."""
+    if min(q, s, p, num_docs) <= 0:
+        return 0, 0
+    n_pb, n_db = pl.cdiv(p, point_block), pl.cdiv(num_docs, doc_block)
+    return q * s * (n_pb + n_db - 1), q * s * n_pb * n_db
+
+
+def _step_shift(n_pb: int, n_db: int) -> int:
+    """Bit offset of the doc block in a step word."""
+    shift = _FLAG_BITS + max(1, (n_pb - 1).bit_length())
+    if shift + max(1, (n_db - 1).bit_length()) > 31:
+        raise ValueError(f"{n_pb} point blocks × {n_db} doc blocks do not "
+                         "fit a refine schedule step word")
+    return shift
+
+
+def _band_schedule(rows, n_steps: int, point_block: int, doc_block: int,
+                   n_doc_blocks: int, shift: int):
+    """rows [S, P_pad] int32 (CSR order, ``-1`` tail) → the banded walk as
+    int32 step words [S · n_steps]: doc block, point block and flags
+    (``_INIT`` on the first step of a doc block's run, ``_COMPUTE`` where
+    the step's point block holds some of its points)."""
+    s, p_pad = rows.shape
+    n_pb = p_pad // point_block
+    keyed = jnp.where(rows < 0, n_doc_blocks * doc_block, rows)
+    bounds = jnp.arange(n_doc_blocks + 1, dtype=jnp.int32) * doc_block
+    starts = jax.vmap(lambda r: jnp.searchsorted(r, bounds))(keyed)
+    lo, hi = starts[:, :-1], starts[:, 1:]         # each doc block's band
+    busy = hi > lo
+    first = jnp.minimum(lo // point_block, n_pb - 1)
+    n = jnp.where(busy, (hi - 1) // point_block - first + 1, 1)
+    ends = jnp.cumsum(n, axis=1)
+    step = jnp.arange(n_steps, dtype=jnp.int32)
+    g = jax.vmap(lambda e: jnp.searchsorted(e, step, side="right"))(ends)
+    live = g < n_doc_blocks
+    g = jnp.minimum(g, n_doc_blocks - 1)           # spare tail: last block
+
+    def at(a):
+        return jnp.take_along_axis(a, g, axis=1)
+
+    n_g = at(n)
+    local = step - (at(ends) - n_g)
+    pb = at(first) + jnp.minimum(local, n_g - 1)
+    flags = (jnp.where(live & (local == 0), _INIT, 0)
+             | jnp.where(live & at(busy), _COMPUTE, 0))
+    words = (g << shift) | (pb << _FLAG_BITS) | flags
+    return words.astype(jnp.int32).reshape(s * n_steps)
+
+
+def _refine_kernel(steps_ref, words_ref, cov_ref, out_ref, *aux_refs,
+                   doc_block: int, n_constraints: int, n_steps: int,
+                   shift: int):
+    """One (query, shard, step) grid step of the banded schedule
+    (``steps_ref``, scalar prefetch).  ``aux_refs`` are the first-hit
+    (hi, lo) planes, then last-hit (hi, lo) and the hit count under
+    analytics; all words are in signed order."""
+    step = steps_ref[pl.program_id(1) * n_steps + pl.program_id(2)]
     top = jnp.int32(_I32_MAX)
     bottom = jnp.int32(_I32_MIN)
 
-    @pl.when(t == 0)
+    @pl.when((step & _INIT) != 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
         for fh in aux_refs[:2]:                    # first-hit planes → sent
@@ -122,6 +202,17 @@ def _refine_kernel(words_ref, cov_ref, out_ref, *aux_refs,
         for cnt in aux_refs[4:]:
             cnt[...] = jnp.zeros_like(cnt)
 
+    @pl.when((step & _COMPUTE) != 0)
+    def _fold():
+        _refine_step(words_ref, cov_ref, out_ref, aux_refs, step >> shift,
+                     doc_block=doc_block, n_constraints=n_constraints)
+
+
+def _refine_step(words_ref, cov_ref, out_ref, aux_refs, g, *,
+                 doc_block: int, n_constraints: int):
+    """Fold one point block into doc block ``g``'s outputs."""
+    top = jnp.int32(_I32_MAX)
+    bottom = jnp.int32(_I32_MIN)
     w = words_ref[0].T                             # (T, 8) int32
     k_hi, k_lo, t_hi, t_lo, rows = (w[:, i:i + 1] for i in range(5))
     docs = g * doc_block + jax.lax.broadcasted_iota(
@@ -239,36 +330,51 @@ def refine_tracks_multi(pts: jnp.ndarray, rows: jnp.ndarray,
         return empty(jnp.full((n_queries, s, num_docs), n_constraints == 0))
     cov = _signed(jnp.stack([_pad_cov(cov[q]) for q in range(n_queries)]))
     r_pad = cov.shape[3]
-    padded_p = pl.cdiv(p, point_block) * point_block
-    padded_d = pl.cdiv(num_docs, doc_block) * doc_block
+    n_pb = pl.cdiv(p, point_block)
+    n_db = pl.cdiv(num_docs, doc_block)
+    n_steps = grid_steps(1, 1, p, num_docs, point_block, doc_block)[0]
+    shift = _step_shift(n_pb, n_db)
+    padded_p = n_pb * point_block
+    padded_d = n_db * doc_block
     words = jnp.zeros((s, 8, padded_p), jnp.int32)
     words = words.at[:, :4, :p].set(_signed(pts))
     words = words.at[:, 4, :].set(-1).at[:, 4, :p].set(rows)
+    steps = _band_schedule(words[:, 4, :], n_steps, point_block, doc_block,
+                           n_db, shift)
+
+    def doc_map(q, i, w, steps_ref):
+        return q, i, 0, steps_ref[i * n_steps + w] >> shift
+
+    def point_map(q, i, w, steps_ref):
+        pb = steps_ref[i * n_steps + w] >> _FLAG_BITS
+        return i, 0, pb & ((1 << (shift - _FLAG_BITS)) - 1)
+
     tbl_shape = jax.ShapeDtypeStruct(
         (n_queries, s, n_constraints, padded_d), jnp.int32)
-    tbl_spec = pl.BlockSpec((1, 1, n_constraints, doc_block),
-                            lambda q, i, g, t: (q, i, 0, g))
+    tbl_spec = pl.BlockSpec((1, 1, n_constraints, doc_block), doc_map)
     outs = pl.pallas_call(
         functools.partial(_refine_kernel, doc_block=doc_block,
-                          n_constraints=n_constraints),
+                          n_constraints=n_constraints, n_steps=n_steps,
+                          shift=shift),
         name="refine_tracks_multi",
-        grid=(n_queries, s, padded_d // doc_block, padded_p // point_block),
-        in_specs=[
-            pl.BlockSpec((1, 8, point_block), lambda q, i, g, t: (i, 0, t)),
-            pl.BlockSpec((1, n_constraints, 8, r_pad),
-                         lambda q, i, g, t: (q, 0, 0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, 1, 1, doc_block),
-                                lambda q, i, g, t: (q, i, 0, g))]
-        + [tbl_spec] * n_tables,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_queries, s, n_steps),
+            in_specs=[
+                pl.BlockSpec((1, 8, point_block), point_map),
+                pl.BlockSpec((1, n_constraints, 8, r_pad),
+                             lambda q, *_: (q, 0, 0, 0)),
+            ],
+            out_specs=[pl.BlockSpec((1, 1, 1, doc_block), doc_map)]
+            + [tbl_spec] * n_tables,
+        ),
         out_shape=[jax.ShapeDtypeStruct((n_queries, s, 1, padded_d),
                                         jnp.int32)]
         + [tbl_shape] * n_tables,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(words, cov)
+    )(steps, words, cov)
     mask = outs[0][:, :, 0, :num_docs] == full
     if not n_tables:
         return mask

@@ -135,20 +135,26 @@ def test_segment_agg_vs_host_groupby(world):
 
 # -------------------------------------------------------- track refine
 
-def _refine_case(rng, n_docs, max_len, n_constraints, *, empty_every=0):
-    """Random ragged tracks + constraints in packed kernel form."""
-    from repro.exec.refine import pack_constraints, pack_track_points
+def _track_lens(rng, n_docs, lens, empty_every=0):
+    """Per-doc track lengths: uniform below ``lens`` when it is a number,
+    else a named layout the banded refine schedule must walk right —
+    ``long``: one track over ≥ 3 point blocks; ``empty_run``: a whole doc
+    block of empty tracks; ``empty``: no points at all."""
+    out = rng.integers(0, lens if isinstance(lens, int) else 12, n_docs)
+    if empty_every:
+        out[::empty_every] = 0                   # force empty tracks
+    if lens == "long":
+        out[n_docs // 2 + 1] = 1_400
+    elif lens == "empty_run":
+        out[120:270] = 0
+    elif lens == "empty":
+        out[:] = 0
+    return out
+
+
+def _constraints(rng, n_constraints):
     from repro.geo import mercator as M
     from repro.geo.areatree import AreaTree
-    lens = rng.integers(0, max_len, n_docs)
-    if empty_every:
-        lens[::empty_every] = 0                  # force empty tracks
-    splits = np.zeros(n_docs + 1, np.int64)
-    np.cumsum(lens, out=splits[1:])
-    p = int(splits[-1])
-    lat = rng.uniform(37.6, 37.9, p)
-    lng = rng.uniform(-122.6, -122.2, p)
-    t = rng.uniform(0.0, 1e5, p)
     cons = []
     for _ in range(n_constraints):
         ix, iy = M.latlng_to_xy(rng.uniform(37.6, 37.9),
@@ -159,10 +165,37 @@ def _refine_case(rng, n_docs, max_len, n_constraints, *, empty_every=0):
                                        max_level=7),
                      float(rng.uniform(0, 5e4)),
                      float(rng.uniform(5e4, 1e5))))
+    return cons
+
+
+def _refine_case(rng, n_docs, lens, n_constraints, *, empty_every=0):
+    """Random ragged tracks (:func:`_track_lens`) + constraints in packed
+    kernel form."""
+    from repro.exec.refine import pack_constraints, pack_track_points
+    splits = np.zeros(n_docs + 1, np.int64)
+    np.cumsum(_track_lens(rng, n_docs, lens, empty_every), out=splits[1:])
+    p = int(splits[-1])
+    lat = rng.uniform(37.6, 37.9, p)
+    lng = rng.uniform(-122.6, -122.2, p)
+    t = rng.uniform(0.0, 1e5, p)
+    cons = _constraints(rng, n_constraints)
     pts, rows = pack_track_points(lat, lng, t, splits)
     return ((lat, lng, t, splits), cons,
             jnp.asarray(pts), jnp.asarray(rows),
             jnp.asarray(pack_constraints(cons)))
+
+
+def _wave(cases):
+    """Shard cases → wave stacks (pts [S, 4, P], rows [S, P]) zero / −1
+    padded to the longest shard, as the backend stacks them."""
+    p_max = max(c[2].shape[1] for c in cases)
+    pts = np.zeros((len(cases), 4, p_max), np.uint32)
+    rows = np.full((len(cases), p_max), -1, np.int32)
+    for i, case in enumerate(cases):
+        p = case[2].shape[1]
+        pts[i, :, :p] = np.asarray(case[2])
+        rows[i, :p] = np.asarray(case[3])
+    return jnp.asarray(pts), jnp.asarray(rows)
 
 
 def _refine_brute(track, cons, n_docs):
@@ -179,13 +212,17 @@ def _refine_brute(track, cons, n_docs):
     return out
 
 
-@pytest.mark.parametrize("n_docs,max_len,c", [(1, 5, 1), (31, 10, 2),
-                                              (128, 8, 1), (300, 12, 3)])
-def test_refine_tracks(n_docs, max_len, c):
+@pytest.mark.parametrize("n_docs,lens,c", [(1, 5, 1), (31, 10, 2),
+                                           (128, 8, 1), (300, 12, 3),
+                                           (300, "long", 2), (640, 40, 2),
+                                           (400, "empty_run", 1)])
+def test_refine_tracks(n_docs, lens, c):
     """Interpret ≡ reference ≡ brute-force numpy on ragged tracks (empty
-    tracks included, doc counts off word boundaries)."""
+    tracks included, doc counts off word boundaries; a track over three
+    point blocks, five doc blocks of several point blocks each, an empty
+    doc block)."""
     rng = np.random.default_rng(n_docs * 7 + c)
-    track, cons, pts, rows, cov = _refine_case(rng, n_docs, max_len, c,
+    track, cons, pts, rows, cov = _refine_case(rng, n_docs, lens, c,
                                                empty_every=5)
     want = _refine_brute(track, cons, n_docs)
     got_i = np.asarray(ops.refine_tracks(pts, rows, cov, n_docs,
@@ -196,42 +233,48 @@ def test_refine_tracks(n_docs, max_len, c):
     assert np.array_equal(got_r, want)
 
 
-@pytest.mark.parametrize("impl", ["interpret", "reference"])
-def test_refine_tracks_batched(impl):
+_WAVES = {"ragged": [(0, 10), (1, 10), (64, 10), (33, 10)],
+          # shards differ in P and doc count, one of them with no points
+          "banded": [(300, "long"), (130, "empty"), (640, 40),
+                     (400, "empty_run")]}
+
+
+@pytest.mark.parametrize("impl,wave", [
+    pytest.param(impl, wave, id=impl if wave == "ragged"
+                 else f"{impl}-{wave}")
+    for wave in _WAVES for impl in ("interpret", "reference")])
+def test_refine_tracks_batched(impl, wave):
     """Wave-stacked refine: ragged shard sizes (incl. an all-empty-track
     shard) padded into one launch ≡ per-shard refine."""
     rng = np.random.default_rng(3)
-    shard_docs = [0, 1, 64, 33]
-    cases = [_refine_case(rng, n, 10, 2, empty_every=3)
-             for n in shard_docs]
+    shards = _WAVES[wave]
+    cases = [_refine_case(rng, n, lens, 2, empty_every=3)
+             for n, lens in shards]
     cov = cases[-1][4]           # same constraints for every shard
     cons = cases[-1][1]
-    n_max = max(shard_docs)
-    p_max = max(c[2].shape[1] for c in cases)
-    pts = np.zeros((len(cases), 4, p_max), np.uint32)
-    rows = np.full((len(cases), p_max), -1, np.int32)
-    for i, case in enumerate(cases):
-        p = case[2].shape[1]
-        pts[i, :, :p] = np.asarray(case[2])
-        rows[i, :p] = np.asarray(case[3])
-    got = np.asarray(ops.refine_tracks_batched(
-        jnp.asarray(pts), jnp.asarray(rows), cov, n_max, impl=impl))
+    n_max = max(n for n, _ in shards)
+    pts, rows = _wave(cases)
+    got = np.asarray(ops.refine_tracks_batched(pts, rows, cov, n_max,
+                                               impl=impl))
     assert got.shape == (len(cases), n_max)
-    for i, (case, n) in enumerate(zip(cases, shard_docs)):
+    for i, (case, (n, _)) in enumerate(zip(cases, shards)):
         want = _refine_brute(case[0], cons, n)
         assert np.array_equal(got[i, :n], want), i
         assert not got[i, n:].any()              # padding never hits
+    assert got.any()                             # non-vacuous evidence
 
 
-@pytest.mark.parametrize("n_docs,max_len,c", [(1, 5, 1), (31, 10, 2),
-                                              (300, 12, 3)])
-def test_refine_tracks_first_hits(n_docs, max_len, c):
+@pytest.mark.parametrize("n_docs,lens,c", [(1, 5, 1), (31, 10, 2),
+                                           (300, 12, 3), (300, "long", 2),
+                                           (640, 40, 3),
+                                           (400, "empty_run", 2)])
+def test_refine_tracks_first_hits(n_docs, lens, c):
     """The first-hit (hi, lo) word tables: interpret ≡ reference ≡ the
     numpy host oracle's packed uint64 min, sentinel where a constraint
     never hits — and the mask output is unchanged by requesting them."""
     from repro.exec.refine import refine_tracks_host
     rng = np.random.default_rng(n_docs * 13 + c)
-    track, cons, pts, rows, cov = _refine_case(rng, n_docs, max_len, c,
+    track, cons, pts, rows, cov = _refine_case(rng, n_docs, lens, c,
                                                empty_every=4)
     lat, lng, t, splits = track
     _, want_table = refine_tracks_host(lat, lng, t, splits, n_docs, cons,
@@ -296,6 +339,110 @@ def test_refine_tracks_empty_inputs(impl):
     assert not np.asarray(ops.refine_tracks(pts, rows, cov, 16,
                                             impl=impl)).any()
 
+
+
+@pytest.mark.parametrize("tables", ["first_hits", "analytics"])
+def test_refine_tracks_multi(tables):
+    """Q = 3 coalesced queries over one wave whose shards differ in P and
+    doc count (a track over three point blocks, an empty doc block, a
+    shard with no points): every output table of the kernel ≡ the jnp
+    oracle's, and the verdicts under ordering edges (and count / dwell
+    reductions) ≡ the numpy host oracle's."""
+    from repro.exec.refine import pack_constraints_multi, refine_tracks_host
+    from repro.kernels import fused
+    rng = np.random.default_rng(14)
+    shards = _WAVES["banded"]
+    cases = [_refine_case(rng, n, lens, 1, empty_every=7)
+             for n, lens in shards]
+    n_max = max(n for n, _ in shards)
+    pts, rows = _wave(cases)
+    cons_list = [_constraints(rng, k) for k in (2, 3, 1)]
+    cov = jnp.asarray(pack_constraints_multi(cons_list))
+    edges = (((0, 1),), ((2, 0), (0, 1)), ())
+    if tables == "analytics":
+        mcs, dws = ((2, 1), (1, 1, 3), (1,)), ((None, 600.0), (), ())
+    else:
+        mcs, dws = ((), (), ()), ((), (), ())
+    flag = {f"with_{tables}": True}
+    got = ops.refine_tracks_multi(pts, rows, cov, n_max, impl="interpret",
+                                  **flag)
+    want = ops.refine_tracks_multi(pts, rows, cov, n_max, impl="reference",
+                                   **flag)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    verdict = {impl: np.asarray(fused._refine_multi_stage(
+        impl, pts, rows, cov, n_max, edges, mcs, dws))
+        for impl in ("interpret", "reference")}
+    assert np.array_equal(verdict["interpret"], verdict["reference"])
+    for q, cons in enumerate(cons_list):
+        for i, (case, (n, _)) in enumerate(zip(cases, shards)):
+            lat, lng, t, splits = case[0]
+            want_q = refine_tracks_host(
+                lat, lng, t, splits, n, cons, edges=edges[q],
+                min_counts=mcs[q] or None, dwells=dws[q] or None)
+            assert np.array_equal(verdict["interpret"][q, i, :n],
+                                  want_q), (q, i)
+    assert verdict["interpret"].any()            # non-vacuous evidence
+
+
+def _csr_rows(rng, n_docs):
+    """rows of a random CSR layout: mostly short tracks, runs of empty
+    ones and now and then a track over several point blocks."""
+    lens = rng.geometric(0.1, n_docs) - 1
+    lens[rng.random(n_docs) < 0.02] = rng.integers(500, 2_000)
+    for _ in range(rng.integers(0, 3)):
+        a = int(rng.integers(0, n_docs))
+        lens[a:a + int(rng.integers(1, 300))] = 0
+    return np.repeat(np.arange(n_docs, dtype=np.int32), lens)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_refine_band_schedule(seed):
+    """The banded walk on random CSR wave layouts: within the static
+    bound, doc blocks nondecreasing, every doc block initialised once on
+    the first step of its run, and every overlapping (doc block, point
+    block) pair computed exactly once — no other."""
+    from repro.kernels import refine as R
+    tb, db = R.DEFAULT_POINT_BLOCK, R.DEFAULT_DOC_BLOCK
+    rng = np.random.default_rng(seed)
+    n_docs = [int(rng.integers(0, 900)) for _ in range(3)]
+    shard_rows = [_csr_rows(rng, n) for n in n_docs]
+    num_docs = max(n_docs) + int(rng.integers(0, 200))
+    p = max(1, max(r.size for r in shard_rows))
+    n_pb, n_db = -(-p // tb), -(-num_docs // db)
+    rows = np.full((len(shard_rows), n_pb * tb), -1, np.int32)
+    for i, r in enumerate(shard_rows):
+        rows[i, :r.size] = r
+    n_steps, dense = R.grid_steps(1, 1, p, num_docs)
+    assert n_steps <= n_pb + n_db and dense == n_pb * n_db
+    shift = R._step_shift(n_pb, n_db)
+    steps = np.asarray(R._band_schedule(jnp.asarray(rows), n_steps, tb, db,
+                                        n_db, shift))
+    steps = steps.reshape(len(shard_rows), n_steps)
+    for i, r in enumerate(shard_rows):
+        g = steps[i] >> shift
+        pb = (steps[i] >> 2) & ((1 << (shift - 2)) - 1)
+        init = (steps[i] & R._INIT) != 0
+        work = (steps[i] & R._COMPUTE) != 0
+        assert (np.diff(g) >= 0).all()
+        assert (pb < n_pb).all()
+        assert np.array_equal(g[init], np.arange(n_db))
+        assert init[0] and (init[1:] == (np.diff(g) > 0)).all()
+        pairs = list(zip(g[work].tolist(), pb[work].tolist()))
+        want = set(zip((r // db).tolist(), (np.arange(r.size) // tb)
+                       .tolist()))
+        assert len(pairs) == len(set(pairs)) and set(pairs) == want
+
+
+def test_refine_grid_steps_at_benchmark_shape():
+    """A sec6_trips shard (6,000 trips, ~115,400 points): the banded walk
+    runs under 3 % of the dense doc-block × point-block grid."""
+    from repro.kernels.refine import grid_steps
+    banded, dense = grid_steps(8, 10, 115_400, 6_000)
+    assert banded / dense < 0.03
+    assert grid_steps(3, 2, 0, 6_000) == (0, 0)
 
 # ------------------------------------------------------ flash attention
 

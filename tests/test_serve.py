@@ -223,6 +223,37 @@ def test_coalesced_launch_contract_and_parity(catalog, walks_db, exec_pplan,
     assert st["fallback_queries"] == 0
 
 
+@pytest.mark.tesseract
+def test_dispatch_spans_count_refine_grid_steps(catalog, walks_db, tmp_path,
+                                                monkeypatch):
+    """A fused refine's ``dispatch`` span, single query and coalesced
+    batch alike, carries the banded grid's steps beside the dense grid's
+    they replace (``kernels.refine.grid_steps``)."""
+    from profile_capture import captured_spans
+    monkeypatch.setenv(FUSED_ENV, "1")
+    flows = _tess_flows()
+    session = Session(catalog=catalog, backend="jax")
+    srv = _server(catalog, cache=False)
+
+    def run():
+        session.run(flows[0])
+        futs = [srv.submit(f) for f in flows]
+        srv.run_pending()
+        for f in futs:
+            f.result(60)
+
+    run()                                      # compiles outside the trace
+    with captured_spans(tmp_path) as got:
+        run()
+    dispatches = [s for s in got if s.name == "dispatch"]
+    assert {"query", "n"} <= {k for s in dispatches for k in s.meta}
+    for s in dispatches:
+        steps, dense = s.meta["refine_steps"], s.meta["refine_dense"]
+        assert 0 < int(steps) <= int(dense), s.meta
+        if "n" in s.meta:
+            assert int(s.meta["n"]) == len(flows)
+
+
 def test_coalesced_agg_tail_parity(catalog, monkeypatch):
     """Aggregating flows coalesce too — the selection rides the multi
     dispatch, the group-by runs in the per-query host tail — and match

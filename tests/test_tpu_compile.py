@@ -89,6 +89,10 @@ def _kernel_cases():
             lambda p, r, c: refine.refine_tracks_multi(
                 p, r, c, TRIPS, with_analytics=True),
             tracks[:2] + [((QUERIES, CONS, 8, RANGES), u32)]),
+        # a served batch of eight plain Q6/Q7 queries
+        "refine_tracks_multi_q8": (
+            lambda p, r, c: refine.refine_tracks_multi(p, r, c, TRIPS),
+            tracks[:2] + [((QUERIES, CONS, 8, RANGES), u32)]),
     }
 
 
@@ -97,6 +101,13 @@ def test_kernel_compiles_for_v5e(name, shape):
     fn, args = _kernel_cases()[name]
     hlo = _compile(fn, *[shape(d, t) for d, t in args])
     assert "tpu_custom_call" in hlo          # the Pallas kernel, lowered
+    if name.startswith("refine"):
+        # the banded walk: its step list leads the kernel's operands as
+        # scalar prefetch, one int32 word per (shard, step)
+        steps = WAVE * refine.grid_steps(1, 1, POINTS, TRIPS)[0]
+        assert re.search(r"%refine_tracks_multi(\.\d+)? = .*"
+                         rf"operand_layout_constraints={{s32\[{steps}\]",
+                         hlo), steps
 
 
 def _fused_cases():
